@@ -58,6 +58,7 @@ COHORT_SEEDS = 8
 # the shared best-of-N timer (repro.obs.timing) — one implementation
 # across every benchmark instead of a copy per file
 from repro.obs.timing import best_of as _best_of  # noqa: E402
+from repro.sim.compile_cache import cold_compiles  # noqa: E402
 
 GP_COHORT_SEEDS = 4
 
@@ -202,9 +203,10 @@ def run(quick: bool = True, out: str = "BENCH_engine.json",
 
     # -- warm-up (jit compile) + result equivalence ---------------------
     host_res = run_sim(cfg, wl)
-    t0 = time.perf_counter()
-    scan_res = run_sim_scan(cfg, wl, chunk=chunk)
-    compile_s = time.perf_counter() - t0
+    with cold_compiles():       # a compile, never a cache load
+        t0 = time.perf_counter()
+        scan_res = run_sim_scan(cfg, wl, chunk=chunk)
+        compile_s = time.perf_counter() - t0
     cohort_res = run_cohort_scan(cfg, seeds, chunk=chunk, wls=wls)
     n_ticks = len(host_res.util_cpu)
     assert len(scan_res.util_cpu) == n_ticks

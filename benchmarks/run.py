@@ -14,8 +14,8 @@
          cohort throughput (+ GP forecast-row overhead); writes
          BENCH_engine.json
   shard  scan cohort vs shard_map device-mesh fleets; writes
-         BENCH_shard.json (run it standalone or first: forced host
-         devices must be configured before jax initializes)
+         BENCH_shard.json (on CPU, set XLA_FLAGS=--xla_force_host_
+         platform_device_count=8 for a mesh)
   kernels  Pallas kernel microbenches
   obs    observability plane: telemetry-ring overhead + identity, trace
          and manifest validity; writes BENCH_obs.json (+ .trace.json /
@@ -43,6 +43,8 @@ import sys
 import time
 import traceback
 
+from repro.sim.compile_cache import enable_compile_cache
+
 SECTIONS = ("fig2", "fig3", "fig4", "fig5", "scenarios", "calibration",
             "engine", "shard", "replay", "kernels", "obs", "roofline")
 
@@ -60,6 +62,7 @@ def main() -> None:
     if args.compare is not None and args.only is None:
         from benchmarks import compare
         sys.exit(compare.main([args.compare]))
+    enable_compile_cache()
     quick = not args.full
     sections = [args.only] if args.only else list(SECTIONS)
     failures = 0
@@ -90,10 +93,9 @@ def main() -> None:
                 from benchmarks import engine
                 engine.run(quick)
             elif sec == "shard":
-                # importing benchmarks.shard forces host devices; if jax
-                # is already initialized (an earlier section ran) the
-                # bench still runs but may see a single device and then
-                # skips the throughput criterion
+                # on CPU the mesh needs forced host devices in the
+                # environment; with one device the bench skips the
+                # throughput criterion
                 from benchmarks import shard
                 shard.run()
             elif sec == "replay":

@@ -9,11 +9,10 @@ quick-grid configuration for
                  ``shard_map`` (``run_fleet_shard``), one SPMD program,
                  host sync only at chunk boundaries.
 
-Runs on CPU via forced host devices: when no ``XLA_FLAGS`` is set the
-bench forces ``--xla_force_host_platform_device_count=8`` itself (the
-flag must be set before jax initializes, which is why the env setup
-precedes the imports).  Writes ``BENCH_shard.json`` recording the
-acceptance criteria:
+Runs on whatever devices JAX sees; on CPU, give it forced host devices
+in the environment (``XLA_FLAGS=--xla_force_host_platform_device_count=8``,
+as CI does).  Writes ``BENCH_shard.json`` recording the acceptance
+criteria:
 
   * bit-identity — ``shard(mesh=1)`` equals the scan cohort per seed,
     and ``shard(mesh>=4)`` equals ``shard(mesh=1)`` per seed;
@@ -22,24 +21,19 @@ acceptance criteria:
 
 Usage::
 
-    python -m benchmarks.shard [--fleet 32] [--out BENCH_shard.json]
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
+        python -m benchmarks.shard [--fleet 32] [--out BENCH_shard.json]
 """
 from __future__ import annotations
-
-import os
-
-# forced host devices MUST be configured before jax's first import;
-# respect an explicit operator choice (CI sets the flag in the job env)
-if "XLA_FLAGS" not in os.environ:
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import argparse
 import dataclasses
 import json
+import os
 import time
 
 from repro.obs.timing import best_of as _best_of
+from repro.sim.compile_cache import cold_compiles
 
 SPEEDUP_FLEET = 2.0       # acceptance: shard vs scan cohort, mesh >= 4
 FLEET_SEEDS = 32
@@ -47,14 +41,9 @@ MESHES = (1, 4, 8)
 
 
 def _results_equal(a, b) -> bool:
-    """Bit-identity over every drained field — the SAME contract as
-    tests/test_shard.py's `_results_equal` (the published criterion
-    must not be weaker than the test suite's definition)."""
-    return (a.summary() == b.summary() and a.turnaround == b.turnaround
-            and a.failed_apps == b.failed_apps
-            and a.util_cpu == b.util_cpu and a.util_mem == b.util_mem
-            and a.slack_cpu == b.slack_cpu and a.slack_mem == b.slack_mem
-            and a.n_running == b.n_running)
+    """Bit-identity over every drained field (``SimResults
+    .first_difference``, the contract the smoke and tests hold)."""
+    return a.first_difference(b) is None
 
 
 def run(out: str = "BENCH_shard.json", fleet: int = FLEET_SEEDS,
@@ -86,10 +75,11 @@ def run(out: str = "BENCH_shard.json", fleet: int = FLEET_SEEDS,
     shard_res: dict[int, list] = {}
     compile_s: dict[int, float] = {}
     for m in meshes:
-        t0 = time.perf_counter()
-        shard_res[m] = run_fleet_shard(cfg, seeds, chunk=chunk, wls=wls,
-                                       mesh=m)
-        compile_s[m] = round(time.perf_counter() - t0, 2)
+        with cold_compiles():   # a compile, never a cache load
+            t0 = time.perf_counter()
+            shard_res[m] = run_fleet_shard(cfg, seeds, chunk=chunk,
+                                           wls=wls, mesh=m)
+            compile_s[m] = round(time.perf_counter() - t0, 2)
     identical_mesh1 = all(_results_equal(a, b) for a, b in
                           zip(scan_res, shard_res[min(meshes)]))
     identical_wide = all(

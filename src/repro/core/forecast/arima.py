@@ -40,6 +40,10 @@ MAX_P = 3
 MAX_Q = 2
 LONG_AR = 6          # stage-1 long-AR order m
 RIDGE = 1e-4
+# a TPU's default f32 matmul is one bf16 pass: on the chip it moved the
+# forecasts' median by 1.8e-4 relative and the worst row by 1.4e2 from
+# the CPU's, against 6e-8 and 5e-3 at full precision
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 @dataclasses.dataclass(frozen=True)
@@ -54,11 +58,11 @@ def _masked_lstsq(A: Array, z: Array, row_mask: Array, col_mask: Array) -> Array
     """Ridge-regularized masked OLS.  Excluded columns get beta = 0."""
     W = row_mask.astype(jnp.float32)
     Aw = A * W[:, None] * col_mask[None, :]
-    G = Aw.T @ Aw + RIDGE * jnp.eye(A.shape[1], dtype=A.dtype)
+    G = jnp.dot(Aw.T, Aw, precision=HIGHEST) + RIDGE * jnp.eye(A.shape[1], dtype=A.dtype)
     # pin excluded columns: identity row forces beta_j = 0
     G = jnp.where(col_mask[:, None] * col_mask[None, :] > 0, G,
                   jnp.eye(A.shape[1], dtype=A.dtype))
-    b = Aw.T @ (z * W)
+    b = jnp.dot(Aw.T, z * W, precision=HIGHEST)
     beta = jnp.linalg.solve(G, b)
     return beta * col_mask
 
@@ -85,7 +89,7 @@ def _fit_arma(z: Array, zmask: Array, p_mask: Array, q_mask: Array,
     rows1 = zmask & jnp.all(ok1, axis=1)
     A1 = jnp.concatenate([jnp.ones((T, 1), z.dtype), L1], axis=1)
     beta1 = _masked_lstsq(A1, z, rows1, jnp.ones((m + 1,), z.dtype))
-    e = jnp.where(rows1, z - A1 @ beta1, 0.0)
+    e = jnp.where(rows1, z - jnp.dot(A1, beta1, precision=HIGHEST), 0.0)
 
     # stage 2: regress z_t on [1, z lags (P), e lags (Q)]
     Lz, okz = _lags(z, cfg.max_p)
@@ -102,7 +106,7 @@ def _fit_arma(z: Array, zmask: Array, p_mask: Array, q_mask: Array,
     A2 = jnp.concatenate([jnp.ones((T, 1), z.dtype), Lz, Le], axis=1)
     cmask = jnp.concatenate([jnp.ones((1,), z.dtype), p_mask, q_mask])
     beta2 = _masked_lstsq(A2, z, rows2, cmask)
-    resid = jnp.where(rows2, z - A2 @ beta2, 0.0)
+    resid = jnp.where(rows2, z - jnp.dot(A2, beta2, precision=HIGHEST), 0.0)
     n_eff = jnp.maximum(rows2.sum(), 1).astype(z.dtype)
     sigma2 = jnp.maximum((resid ** 2).sum() / n_eff, 1e-10)
     delta = beta2[0]

@@ -79,14 +79,51 @@ class Forecaster(Protocol):
         ...
 
 
+# Rows per fixed-shape tile of a batched forecast.  A TPU compiler picks
+# fusions, and with them rounding, by batch size: a row's forecast is
+# bit-identical across batch sizes only when every row is computed in a
+# program of one shape.  The engines' bucketed, cohort and sharded paths
+# rely on that.  16 is the smallest bucketed pass (2 x 8 rows).
+TILE_ROWS = 16
+
+
 def batched(forecast_fn, windows: Array, horizon: int,
             valid: Array | None = None) -> Forecast:
-    """vmap a single-series forecast fn over (B, T) windows."""
+    """vmap a single-series forecast fn over (B, T) windows, as a loop
+    over ``TILE_ROWS``-row tiles; an outer ``vmap`` (a seed cohort, a
+    fleet's rows) folds its axis into the rows, so every row runs in
+    the same tile program whatever the batch."""
     if valid is None:
         valid = jnp.ones(windows.shape, dtype=bool)
+
     def fn(w, v):
         return forecast_fn(w, horizon, valid=v)
-    return jax.vmap(fn)(windows, valid)
+    return _tiled(fn)(windows, valid)
+
+
+def _tiled(row_fn):
+    @jax.custom_batching.custom_vmap
+    def run(*xs):
+        n = xs[0].shape[0]
+        pad = -n % TILE_ROWS
+        tiles = tuple(
+            jnp.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1)).reshape(
+                (-1, TILE_ROWS) + x.shape[1:]) for x in xs)
+        out = jax.lax.map(lambda t: jax.vmap(row_fn)(*t), tiles)
+        return jax.tree.map(lambda y: y.reshape((-1,) + y.shape[2:])[:n],
+                            out)
+
+    @run.def_vmap
+    def _fold(axis_size, in_batched, *xs):
+        xs = [x if b else jnp.broadcast_to(x, (axis_size,) + x.shape)
+              for x, b in zip(xs, in_batched)]
+        n = xs[0].shape[1]
+        out = run(*[x.reshape((axis_size * n,) + x.shape[2:]) for x in xs])
+        out = jax.tree.map(
+            lambda y: y.reshape((axis_size, n) + y.shape[1:]), out)
+        return out, jax.tree.map(lambda _: True, out)
+
+    return run
 
 
 def peak_over_horizon(fc: Forecast) -> tuple[Array, Array]:

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 
 from repro.core.forecast.base import Forecast, batched
 from repro.kernels import ops as kops
+from repro.kernels.fixed_order import tree_sum
 
 Array = jax.Array
 
@@ -78,11 +79,93 @@ def build_patterns(window: Array, h: int, n: int) -> tuple[Array, Array, Array]:
 
 def _standardize(y: Array, valid: Array) -> tuple[Array, Array, Array]:
     w = valid.astype(y.dtype)
-    cnt = jnp.maximum(w.sum(), 1.0)
-    mu = (y * w).sum() / cnt
-    var = ((y - mu) ** 2 * w).sum() / cnt
+    cnt = jnp.maximum(tree_sum(w), 1.0)
+    mu = tree_sum(y * w) / cnt
+    var = tree_sum((y - mu) ** 2 * w) / cnt
     sd = jnp.sqrt(jnp.maximum(var, 1e-10))
     return (y - mu) / sd, mu, sd
+
+
+# The GP's linear algebra is N x N with N = max_patterns (10), and every
+# sum in it is a fixed-order tree (``kernels.fixed_order``): a library
+# Cholesky, triangular solve or matvec lets the compiler order its adds by
+# layout, and a forecast row must read the same bits in a solo program as
+# in a vmapped cohort or sharded fleet.
+
+def _cholesky(a: Array) -> Array:
+    """Lower Cholesky factor of the symmetric part of ``a``, one column
+    at a time, each taken out of the trailing matrix by an outer
+    product (so entry (i, j) is a_ij - l_i0 l_j0 - l_i1 l_j1 - ...)."""
+    a = 0.5 * (a + a.T)
+    n = a.shape[0]
+    rows = jnp.arange(n)
+    cols = []
+    for j in range(n):
+        d = jnp.sqrt(a[j, j])
+        c = jnp.where(rows > j, a[:, j] / d, jnp.where(rows == j, d, 0.0))
+        cols.append(c)
+        a = a - c[:, None] * c[None, :]
+    return jnp.stack(cols, axis=1)
+
+
+def _solve_lower(L: Array, b: Array) -> Array:
+    """Solve L z = b for z; ``b`` is (n,) or (n, m).  Each solved entry's
+    column is subtracted from the rest (forward substitution)."""
+    n = L.shape[0]
+    z = []
+    for k in range(n):
+        z.append(b[k] / L[k, k])
+        b = b - L[:, k].reshape((n,) + (1,) * (b.ndim - 1)) * z[k]
+    return jnp.stack(z)
+
+
+def _cho_solve(L: Array, b: Array) -> Array:
+    """Solve (L L^T) x = b for x: forward, then back substitution."""
+    n = L.shape[0]
+    z = _solve_lower(L, b)
+    x = [None] * n
+    for k in reversed(range(n)):
+        x[k] = z[k] / L[k, k]
+        z = z - L[k, :].reshape((n,) + (1,) * (z.ndim - 1)) * x[k]
+    return jnp.stack(x)
+
+
+def _diag(a: Array) -> Array:
+    """The diagonal as a gather (``jnp.diagonal`` is a masked reduce)."""
+    i = jnp.arange(a.shape[0])
+    return a[i, i]
+
+
+@jax.custom_vjp
+def _evidence(K: Array, s2: Array, y: Array, row_valid: Array) -> Array:
+    """Negative log marginal likelihood of ``y`` under the Gram ``K``
+    plus noise ``s2`` on the valid rows."""
+    return _evidence_fwd(K, s2, y, row_valid)[0]
+
+
+def _evidence_fwd(K, s2, y, row_valid):
+    # invalid rows: decouple them with enormous noise so they carry no info
+    noise = jnp.where(row_valid, s2, 1e6)
+    L = _cholesky(K + jnp.diag(noise))
+    alpha = _cho_solve(L, y)
+    n_eff = row_valid.sum().astype(y.dtype)
+    nll = (0.5 * tree_sum(y * alpha)
+           + tree_sum(jnp.where(row_valid, jnp.log(_diag(L)), 0.0))
+           + 0.5 * n_eff * jnp.log(2.0 * jnp.pi))
+    return nll, (L, alpha, row_valid)
+
+
+def _evidence_bwd(res, g):
+    # d nll / dK = (K^-1 - alpha alpha^T) / 2 (Rasmussen & Williams
+    # eq. 5.9), written out so the backward pass holds no reduce either
+    L, alpha, row_valid = res
+    kinv = _cho_solve(L, jnp.eye(L.shape[0], dtype=L.dtype))
+    dK = 0.5 * g * (kinv - alpha[:, None] * alpha[None, :])
+    ds2 = tree_sum(jnp.where(row_valid, _diag(dK), 0.0))
+    return dK, ds2, g * alpha, None
+
+
+_evidence.defvjp(_evidence_fwd, _evidence_bwd)
 
 
 def _neg_log_marginal(log_params: Array, X: Array, y: Array,
@@ -90,15 +173,7 @@ def _neg_log_marginal(log_params: Array, X: Array, y: Array,
                       impl: str) -> Array:
     ell, sf, sn = jnp.exp(log_params)
     K = kops.gram(X, X, ell, sf, kind=kernel, impl=impl)
-    # invalid rows: decouple them with enormous noise so they carry no info
-    noise = jnp.where(row_valid, sn ** 2 + jitter, 1e6)
-    K = K + jnp.diag(noise)
-    L = jnp.linalg.cholesky(K)
-    alpha = jax.scipy.linalg.cho_solve((L, True), y)
-    n_eff = row_valid.sum().astype(y.dtype)
-    return (0.5 * y @ alpha
-            + jnp.sum(jnp.where(row_valid, jnp.log(jnp.diagonal(L)), 0.0))
-            + 0.5 * n_eff * jnp.log(2.0 * jnp.pi))
+    return _evidence(K, sn ** 2 + jitter, y, row_valid)
 
 
 def _optimize_evidence(X, y, row_valid, cfg: GPConfig) -> Array:
@@ -155,8 +230,8 @@ class GPForecaster:
 
         K = kops.gram(X, X, ell, sf, kind=cfg.kernel, impl=cfg.impl)
         noise = jnp.where(row_valid, sn ** 2 + cfg.jitter, 1e6)
-        L = jnp.linalg.cholesky(K + jnp.diag(noise))
-        alpha = jax.scipy.linalg.cho_solve((L, True), y)
+        L = _cholesky(K + jnp.diag(noise))
+        alpha = _cho_solve(L, y)
 
         # iterated k-step-ahead: feed the predictive mean back into the
         # history (standard for NARX-style GP forecasting); the predictive
@@ -167,9 +242,9 @@ class GPForecaster:
             t_next = (T + k) / max(T - 1, 1)
             xs = jnp.concatenate([jnp.asarray([t_next], jnp.float32), hist])[None, :]
             ks = kops.gram(xs, X, ell, sf, kind=cfg.kernel, impl=cfg.impl)[0]
-            mean_k = ks @ alpha
-            v = jax.scipy.linalg.cho_solve((L, True), ks)
-            var_k = sf ** 2 + sn ** 2 - ks @ v
+            mean_k = tree_sum(ks * alpha)
+            v = _solve_lower(L, ks)         # ks^T K^-1 ks = |L^-1 ks|^2
+            var_k = sf ** 2 + sn ** 2 - tree_sum(v * v)
             var_k = jnp.maximum(var_k, 1e-9)
             means.append(mean_k)
             variances.append(var_k)

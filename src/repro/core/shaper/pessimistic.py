@@ -28,6 +28,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.fixed_order import onehot_sum, tree_sum
+
 Array = jax.Array
 
 
@@ -94,8 +96,11 @@ def pessimistic_shape_raw(p: ShapeProblem) -> ShapeDecision:
     row_dem = jnp.stack([p.comp_cpu[a_all], p.comp_mem[a_all]], -1)
     core = exists & is_core
     host_oh = host[:, :, None] == jnp.arange(H)[None, None, :]  # (A, C, H)
-    core_dem_all = jnp.where((core[:, :, None] & host_oh)[..., None],
-                             row_dem[:, :, None, :], 0.0).sum(1)  # (A, H, 2)
+    # a fixed-order sum (``kernels.fixed_order``): an app's core
+    # components on one host add in the same order in every program
+    core_dem_all = tree_sum(
+        jnp.where((core[:, :, None] & host_oh)[..., None],
+                  row_dem[:, :, None, :], 0.0), 1)           # (A, H, 2)
     order = elastic_order[a_all]                             # (A, C)
     ar = jnp.arange(A)[:, None]
     ord_dem = row_dem[ar, order]                             # (A, C, 2)
@@ -118,7 +123,7 @@ def pessimistic_shape_raw(p: ShapeProblem) -> ShapeDecision:
         def comp_step(f, x2):
             dem, el_c, oh = x2                   # (2,), (), (H,)
             is_el = commit_core & el_c
-            tcm = jnp.where(oh[:, None], f, 0.0).sum(0) - dem    # (2,)
+            tcm = onehot_sum(oh[:, None], f, 0) - dem            # (2,)
             kill_c = is_el & jnp.any(tcm <= 0.0)
             commit = is_el & ~kill_c
             f = f - jnp.where((oh & commit)[:, None], dem, 0.0)

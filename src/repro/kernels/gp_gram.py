@@ -13,8 +13,8 @@ The kernel fuses the distance computation with the kernel application
 TPU adaptation notes (vs a CUDA pairwise-distance kernel):
   * tiles are MXU/VPU aligned — D is padded to a multiple of 128 (lane
     dim) by the wrapper in ops.py; M/N tiles are multiples of 8 (sublane);
-  * the -2 a.b term is a (bm, D) x (D, bn) matmul hitting the MXU with
-    fp32 accumulation via ``preferred_element_type``;
+  * the -2 a.b term is a (bm, D) x (D, bn) matmul hitting the MXU at
+    HIGHEST precision with fp32 accumulation;
   * hyper-parameters (ell, sf) arrive as a small VMEM vector so the same
     compiled kernel serves every evidence-maximization step.
 
@@ -45,9 +45,11 @@ def _gram_kernel(xa_ref, xb_ref, params_ref, out_ref, *, kind: str):
     sf = params_ref[0, 1]
     na = jnp.sum(xa * xa, axis=1, keepdims=True)    # (bm, 1)
     nb = jnp.sum(xb * xb, axis=1, keepdims=True).T  # (1, bn)
-    # MXU matmul with fp32 accumulate
+    # MXU matmul at full f32 precision: the distance identity cancels,
+    # so bf16-rounded operands would corrupt d2
     ab = jax.lax.dot_general(
         xa, xb, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)         # (bm, bn)
     d2 = jnp.maximum(na + nb - 2.0 * ab, 0.0)
     if kind == "exp":

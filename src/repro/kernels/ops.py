@@ -9,12 +9,15 @@ backend dispatch.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 from repro.kernels import flash_attention as _fa
 from repro.kernels import gp_gram as _gg
 from repro.kernels import ref
+from repro.kernels.fixed_order import tree_sum
 
 Array = jax.Array
 
@@ -42,9 +45,23 @@ def _pad_axis(x: Array, axis: int, to: int) -> Array:
 
 def gram(xa: Array, xb: Array, lengthscale, sigma_f, *, kind: str = "exp",
          impl: str = "auto") -> Array:
-    """Gram matrix k_h(xa, xb) (paper Eq. 6). xa: (M,D), xb: (N,D)."""
+    """Gram matrix k_h(xa, xb) (paper Eq. 6). xa: (M,D), xb: (N,D).
+
+    Differentiable on every path: the Pallas forward carries a
+    ``custom_vjp`` whose backward is the VJP of :func:`ref.gram`, so the
+    GP evidence loss keeps the kernel in its forward pass under
+    ``jax.grad`` (``pallas_call`` has no reverse-mode rule of its own).
+    """
     if impl == "jnp" or (impl == "auto" and not _on_tpu()):
         return ref.gram(xa, xb, lengthscale, sigma_f, kind=kind)
+    return _pallas_gram(xa, xb, jnp.asarray(lengthscale, jnp.float32),
+                        jnp.asarray(sigma_f, jnp.float32), kind,
+                        not _on_tpu())
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _pallas_gram(xa: Array, xb: Array, ell: Array, sf: Array, kind: str,
+                 interpret: bool) -> Array:
     M, D = xa.shape
     N = xb.shape[0]
     # pick tiles: small problems use one tile, large problems 128x128
@@ -55,11 +72,31 @@ def gram(xa: Array, xb: Array, lengthscale, sigma_f, *, kind: str = "exp",
     xa_p = _pad_axis(_pad_axis(xa.astype(jnp.float32), 1, Dp), 0, Mp)
     xb_p = _pad_axis(_pad_axis(xb.astype(jnp.float32), 1, Dp), 0, Np)
     params = jnp.zeros((1, 128), jnp.float32)
-    params = params.at[0, 0].set(jnp.asarray(lengthscale, jnp.float32))
-    params = params.at[0, 1].set(jnp.asarray(sigma_f, jnp.float32))
+    params = params.at[0, 0].set(ell)
+    params = params.at[0, 1].set(sf)
     out = _gg.gp_gram(xa_p, xb_p, params, kind=kind, bm=bm, bn=bn,
-                      interpret=not _on_tpu())
+                      interpret=interpret)
     return out[:M, :N]
+
+
+def _pallas_gram_fwd(xa, xb, ell, sf, kind, interpret):
+    return _pallas_gram(xa, xb, ell, sf, kind, interpret), (xa, xb, ell, sf)
+
+
+def _pallas_gram_bwd(kind, interpret, res, g):
+    # the VJP of ref.gram with ell and sf spread over the (M, N) grid,
+    # so each parameter's cotangent is one fixed-order sum of per-entry
+    # terms (a broadcast's transpose would be a compiler-ordered reduce)
+    xa, xb, ell, sf = res
+    grid = (xa.shape[0], xb.shape[0])
+    _, vjp = jax.vjp(lambda *a: ref.gram(*a, kind=kind), xa, xb,
+                     jnp.full(grid, ell), jnp.full(grid, sf))
+    g_xa, g_xb, g_ell, g_sf = vjp(g)
+    return (g_xa, g_xb, tree_sum(tree_sum(g_ell, 1), 0),
+            tree_sum(tree_sum(g_sf, 1), 0))
+
+
+_pallas_gram.defvjp(_pallas_gram_fwd, _pallas_gram_bwd)
 
 
 # ----------------------------------------------------------------------

@@ -10,6 +10,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.fixed_order import tree_sum
+
 Array = jax.Array
 
 
@@ -19,9 +21,15 @@ Array = jax.Array
 
 def sq_dists(xa: Array, xb: Array) -> Array:
     """Pairwise squared Euclidean distances, (M,D) x (N,D) -> (M,N)."""
-    na = jnp.sum(xa * xa, axis=-1)
-    nb = jnp.sum(xb * xb, axis=-1)
-    d2 = na[:, None] + nb[None, :] - 2.0 * (xa @ xb.T)
+    # every sum in f32 adds of a fixed order (no matmul: a TPU's default
+    # f32 matmul is one bf16 pass, and the cancellation in this identity
+    # would turn that into large distance errors for near-identical
+    # patterns; and the GP's gradient needs the same bits in a solo and
+    # a batched program, see ``fixed_order``)
+    na = tree_sum(xa * xa, -1)
+    nb = tree_sum(xb * xb, -1)
+    ab = tree_sum(xa[:, None, :] * xb[None, :, :], -1)
+    d2 = na[:, None] + nb[None, :] - 2.0 * ab
     return jnp.maximum(d2, 0.0)
 
 

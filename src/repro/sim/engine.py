@@ -158,8 +158,9 @@ def _jitted_peak_forecast(model, horizon: int, b: int, width: int):
 def forecast_peaks(model, horizon: int, windows: np.ndarray,
                    valid: np.ndarray):
     """Pad (n, W) windows to a power-of-two bucket and run the shared
-    jitted peak forecast.  Row i's result depends only on row i (verified
-    bit-identical across bucket sizes), so callers may freely stack
+    jitted peak forecast.  Row i's result depends only on row i (every
+    batch runs in the same fixed-size tiles, ``forecast.base.batched``;
+    verified bit-identical across bucket sizes), so callers may freely stack
     windows from many sims into one call."""
     n, width = windows.shape
     b = _bucket(n)

@@ -7,6 +7,9 @@ import numpy as np
 
 CPU, MEM = 0, 1
 
+# SimResults' per-tick series
+SERIES = ("slack_cpu", "slack_mem", "util_cpu", "util_mem", "n_running")
+
 # summary keys the sweep aggregates across seeds (paper's Fig. 3-4 axes:
 # turnaround, failures, slack / utilization)
 AGGREGATE_KEYS = (
@@ -137,3 +140,27 @@ class SimResults:
         if self.tenancy is not None:
             out["tenancy"] = self.tenancy
         return out
+
+    def first_difference(self, other: "SimResults") -> str | None:
+        """Where two results first differ over every drained field
+        (``None`` if they are bit-identical) — the engines' equivalence
+        contracts.  NaNs compare equal (an empty run's summary)."""
+        def same(x, y):
+            return x == y or repr(x) == repr(y)
+
+        for f in ("summary", "turnaround", "failed_apps") + SERIES:
+            x, y = ((self.summary(), other.summary()) if f == "summary"
+                    else (getattr(self, f), getattr(other, f)))
+            if same(x, y):
+                continue
+            if isinstance(x, list) and len(x) == len(y):
+                i = next(i for i, (p, q) in enumerate(zip(x, y))
+                         if not same(p, q))
+                return f"{f}[{i}] of {len(x)}: {x[i]!r} vs {y[i]!r}"
+            if isinstance(x, dict):
+                k = sorted(k for k in set(x) | set(y)
+                           if not same(x.get(k), y.get(k)))
+                return f"{f}: {len(k)} keys differ, first {k[0]!r}: " \
+                       f"{x.get(k[0])!r} vs {y.get(k[0])!r}"
+            return f"{f}: {str(x)[:300]} vs {str(y)[:300]}"
+        return None

@@ -45,6 +45,7 @@ sweep-level executor that groups grid cells into fleets lives in
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 
 import jax
@@ -60,6 +61,7 @@ from repro.core.shaper.safeguard import (shaped_demand_raw,
                                          shaped_demand_scaled_raw)
 from repro.core.uncertainty.online import (calib_begin, calib_observe,
                                            calib_scales)
+from repro.kernels.fixed_order import onehot_sum, tree_sum
 from repro.obs import REGISTRY, span
 from repro.obs.rings import RING_FIELDS, RingDrain, obs_record
 from repro.sim.metrics import SimResults
@@ -127,8 +129,8 @@ def _free_resources(st: SimState, host_cap: Array) -> Array:
     live = st.comp_running.reshape(-1)
     host = st.comp_host.reshape(-1)
     mask = live[:, None] & (host[:, None] == jnp.arange(H)[None, :])
-    used = jnp.where(mask[:, :, None],
-                     st.alloc.reshape(-1, 2)[:, None, :], 0.0).sum(0)
+    used = tree_sum(jnp.where(mask[:, :, None],
+                              st.alloc.reshape(-1, 2)[:, None, :], 0.0), 0)
     return host_cap - used
 
 
@@ -211,6 +213,13 @@ def _record_monitor(st: SimState, usage: Array) -> SimState:
     return dataclasses.replace(st, mon_buf=buf, mon_count=cnt)
 
 
+def _cluster_total(x: Array) -> Array:
+    """Sum an (A, C, ...) table over slots and components in one fixed
+    order of f32 adds (``kernels.fixed_order``): a solo program and a
+    vmapped cohort or fleet one must read the same bits."""
+    return tree_sum(tree_sum(x, 1), 0)
+
+
 def _oracle_peaks(tr: DeviceTrace, st: SimState, horizon: int,
                   tick: float) -> Array:
     """(A, C, 2) true future peak usage over the horizon (variance 0)."""
@@ -242,8 +251,8 @@ def _bucketed_forecast(cfg, model, wins: Array, valid: Array,
     (mean, var) bit-identical to the full-batch path; non-ready rows
     come back 0, which downstream masking (``ready2`` in
     ``_shaped_demands``, ``deploy`` in ``calib_begin``) never reads.
-    The scatter-back is a one-hot matmul, not ``.at[].set`` — XLA CPU
-    scatters serialize under the cohort vmap.
+    The scatter-back is a one-hot masked sum, not ``.at[].set`` — XLA
+    CPU scatters serialize under the cohort vmap.
 
     Returns (mean, var, n_pass), each over the full ``(2 * AC,)`` row
     space; ``n_pass * 2 * bucket`` is the rows the model actually
@@ -276,11 +285,16 @@ def _bucketed_forecast(cfg, model, wins: Array, valid: Array,
         pvar = pvar.astype(jnp.float32)
         # one-hot scatter-back: each ready row appears in exactly one
         # pass (argsort is a permutation; the sentinel tail is masked),
-        # so each output element is one value plus exact zeros
-        oh = ((idx[:, None] == cols[None, :])
-              & in_pass[:, None]).astype(jnp.float32)      # (B, AC)
-        mean = mean + jnp.concatenate([peak[:B] @ oh, peak[B:] @ oh])
-        var = var + jnp.concatenate([pvar[:B] @ oh, pvar[B:] @ oh])
+        # so each output element is one value plus exact zeros.  A
+        # masked select-and-sum, not a one-hot matmul: a TPU's default
+        # f32 matmul would round the values to bf16
+        oh = (idx[:, None] == cols[None, :]) & in_pass[:, None]  # (B, AC)
+
+        def back(x):
+            return onehot_sum(oh, x[:, None], 0)
+
+        mean = mean + jnp.concatenate([back(peak[:B]), back(peak[B:])])
+        var = var + jnp.concatenate([back(pvar[:B]), back(pvar[B:])])
         return p + 1, mean, var
 
     z = jnp.zeros((2 * AC,), jnp.float32)
@@ -436,7 +450,7 @@ def _apply_decision(cfg, tr: DeviceTrace, st: SimState, dec,
     if not cfg.work_lost_on_kill:
         saved = jnp.where(
             kgids_mask,
-            jnp.where(slot_of, st.work_done[:, None], 0.0).sum(0),
+            onehot_sum(slot_of, st.work_done[:, None], 0),
             st.saved_work)
         has = st.has_saved | kgids_mask
         st = dataclasses.replace(st, saved_work=saved, has_saved=has)
@@ -481,8 +495,8 @@ def _resolve_oom(tr: DeviceTrace, st: SimState, usage: Array,
     on_host = (st.comp_running.reshape(-1)[:, None]
                & (st.comp_host.reshape(-1)[:, None]
                   == jnp.arange(H)[None, :]))             # (A*C, H)
-    over0 = (jnp.where(on_host, usage[:, :, MEM].reshape(-1)[:, None],
-                       0.0).sum(0)
+    over0 = (tree_sum(jnp.where(on_host,
+                                usage[:, :, MEM].reshape(-1)[:, None], 0.0), 0)
              > host_cap[:, MEM] + 1e-6)
     # victims are running at selection time, so their gid (and coreness)
     # cannot have changed since loop entry — gather the tables once
@@ -499,10 +513,10 @@ def _resolve_oom(tr: DeviceTrace, st: SimState, usage: Array,
          failed, queued, monreset, oom_kills, fevents, partials) = carry
         on_h = comp_running & (st.comp_host == h)
         mem = usage[:, :, MEM]
-        tot = jnp.where(on_h, mem, 0.0).sum()
+        tot = _cluster_total(jnp.where(on_h, mem, 0.0))
         oh = jnp.arange(H) == h
         need = (jnp.where(oh, over0, False).any() & on_h.any()
-                & (tot > jnp.where(oh, cap_mem, 0.0).sum() + 1e-6))
+                & (tot > onehot_sum(oh, cap_mem) + 1e-6))
 
         over = jnp.where(on_h, mem - alloc[:, :, MEM], -jnp.inf)
         flat = over.reshape(-1)
@@ -636,7 +650,7 @@ def _admit_queued(cfg, tr: DeviceTrace, st: SimState, t: Array,
             resume = jnp.float32(0.0)
         else:   # preempt-to-checkpoint: resume from the saved progress
             resume = jnp.where((ogid & cur.has_saved).any(),
-                               jnp.where(ogid, cur.saved_work, 0.0).sum(),
+                               onehot_sum(ogid, cur.saved_work),
                                0.0)
         osl = (jnp.arange(A) == slot) & ok           # one-hot slot
         row = lambda x, new: jnp.where(  # noqa: E731
@@ -693,8 +707,8 @@ def _place_missing_elastic(tr: DeviceTrace, st: SimState, t: Array,
             i, free, comp_running, comp_host, alloc, alive = carry
             oe = jnp.arange(A * C) == order[i]
             m2 = oe.reshape(A, C)                          # one-hot (A, C)
-            cpu = jnp.where(m2, req_cpu, 0.0).sum()
-            mem = jnp.where(m2, req_mem, 0.0).sum()
+            cpu = onehot_sum(m2, req_cpu)
+            mem = onehot_sum(m2, req_mem)
             h, fits = _worst_fit(free, cpu, mem)
             oh = (jnp.arange(H) == h) & fits
             free = free - jnp.where(oh[:, None],
@@ -889,8 +903,9 @@ def fused_tick(cfg, model, tr: DeviceTrace, st: SimState,
     st = _mon_reset(st, resets | resets5 | resets6)
 
     # 7. metrics (raw sums; the ratios divide on the host at drain)
-    used = usage.sum((0, 1))
-    alloc = jnp.where(st.comp_running[:, :, None], st.alloc, 0.0).sum((0, 1))
+    used = _cluster_total(usage)
+    alloc = _cluster_total(jnp.where(st.comp_running[:, :, None], st.alloc,
+                                     0.0))
     metrics = TickMetrics(
         valid=active,
         n_running=(st.slot_gid >= 0).sum().astype(jnp.int32),
@@ -1044,9 +1059,11 @@ def _timed_first_call(fn, metric: str):
     """Wrap a fresh jitted chunk fn so its FIRST call — which traces and
     compiles synchronously before dispatching — is measured: the wall
     feeds a ``repro.obs`` histogram (manifests snapshot it) and a
-    ``jit_compile`` trace span.  Later calls pass straight through."""
+    ``jit_compile`` trace span.  Later calls pass straight through;
+    ``wrapped.__wrapped__`` is the jitted fn."""
     holder = {"first": True}
 
+    @functools.wraps(fn)
     def wrapped(*args):
         if holder["first"]:
             holder["first"] = False
@@ -1324,15 +1341,9 @@ def run_cohort_scan(cfg, seeds, *, chunk: int = 32,
 # sharded fleet driver (shard_map over a device mesh)
 # ----------------------------------------------------------------------
 
-def _resolve_mesh(mesh, fleet_size: int):
+def _resolve_mesh(mesh):
     """Normalize ``mesh`` (None = all local devices, int = first N
-    devices, or a ready-made ``Mesh``) to a 1-D fleet mesh.
-
-    The mesh is capped so every device holds at least TWO fleet rows:
-    a device with zero rows would idle, and jaxlib 0.4.x's CPU
-    partitioner SIGFPEs compiling a ``shard_map`` whose per-device
-    slice of this program is exactly 1 (padding past the crash would
-    cost the same wasted compute the cap avoids)."""
+    devices, or a ready-made ``Mesh``) to a 1-D fleet mesh."""
     from jax.sharding import Mesh
     if isinstance(mesh, Mesh):
         return mesh
@@ -1343,8 +1354,7 @@ def _resolve_mesh(mesh, fleet_size: int):
                          f"({len(devs)} visible; set XLA_FLAGS="
                          "--xla_force_host_platform_device_count=N for "
                          "forced host devices on CPU)")
-    cap = max(1, round_up(fleet_size, 2) // 2)
-    return Mesh(np.array(devs[:min(n, cap)]), (FLEET_AXIS,))
+    return Mesh(np.array(devs[:n]), (FLEET_AXIS,))
 
 
 def _shard_chunk_fn(cfg, chunk: int, shapes, mesh,
@@ -1355,8 +1365,6 @@ def _shard_chunk_fn(cfg, chunk: int, shapes, mesh,
     collectives: sims never communicate), so one SPMD program executes
     the whole fleet with host sync only at chunk boundaries."""
     from jax.sharding import PartitionSpec as P
-
-    from repro.distributed.shmap import no_check_kwargs, shard_map
     key = (_cfg_key(cfg), chunk, shapes, "shard", bucket,
            tuple(d.id for d in mesh.devices.flat))
     fn = _CHUNK_CACHE.get(key)
@@ -1365,10 +1373,10 @@ def _shard_chunk_fn(cfg, chunk: int, shapes, mesh,
         run_chunk, donate = _chunk_body(cfg, model, chunk, bucket)
         spec = P(FLEET_AXIS)
         n_args = 1 + len(donate)        # (tr, st[, left])
-        sharded = shard_map(jax.vmap(run_chunk), mesh=mesh,
-                            in_specs=(spec,) * n_args,
-                            out_specs=(spec,) * n_args,
-                            **no_check_kwargs())
+        sharded = jax.shard_map(jax.vmap(run_chunk), mesh=mesh,
+                                in_specs=(spec,) * n_args,
+                                out_specs=(spec,) * n_args,
+                                check_vma=False)
         fn = _CHUNK_CACHE[key] = _timed_first_call(
             jax.jit(sharded, donate_argnums=donate), "shard.compile_s")
         if bucket is not None:
@@ -1398,9 +1406,10 @@ def run_fleet_shard(cfg, seeds=None, *, chunk: int = 32, wls=None,
 
     Correctness anchors (``tests/test_shard.py``): ``mesh=1`` is
     bit-identical to ``run_cohort_scan``, and any larger mesh is
-    bit-identical per member to ``mesh=1`` (XLA CPU reductions are
-    batch-size invariant, so re-slicing the fleet axis cannot change a
-    member's numerics).  Padding members are real sims whose results
+    bit-identical per member to ``mesh=1`` (forecasts run in fixed-size
+    tiles, ``forecast.base.batched``, and the rest of a tick is per
+    member, so re-slicing the fleet axis cannot change a member's
+    numerics).  Padding members are real sims whose results
     are simply never drained.
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -1436,11 +1445,8 @@ def run_fleet_shard(cfg, seeds=None, *, chunk: int = 32, wls=None,
         raise ValueError(f"fleet traces disagree on shape: {shapes}")
 
     B = len(cfgs)
-    mesh = _resolve_mesh(mesh, B)
-    m = int(mesh.devices.size)
-    # >= 2 rows per device (see _resolve_mesh); an explicitly passed
-    # Mesh wider than B/2 is honored by padding up to 2 rows per device
-    padded = round_up(B, m) if m == 1 else round_up(max(B, 2 * m), m)
+    mesh = _resolve_mesh(mesh)
+    padded = round_up(B, int(mesh.devices.size))
     sharding = NamedSharding(mesh, P(FLEET_AXIS))
     tr = _device_trace(wls, batched=True, pad_to=padded,
                        place=lambda t: jax.device_put(t, sharding),

@@ -56,6 +56,7 @@ from repro.obs import (DEFAULT_RULES, REGISTRY, bucketed_row_overhead,
                        write_alert_log, obs_summary, span, tracing,
                        write_manifest)
 from repro.sim.cluster import ClusterConfig
+from repro.sim.compile_cache import enable_compile_cache
 from repro.sim.engine import (SimConfig, _BatchedForecaster, _make_model,
                               forecast_peaks, run_sim)
 from repro.sim.metrics import aggregate_summaries, trace_stats
@@ -377,10 +378,7 @@ class SweepResult:
     # diagnostics (schema 3; attached when the grid sweeps calibration)
     calibration: list = dataclasses.field(default_factory=list)
     # which engine actually ran the grid (additive schema-3 keys).
-    # mesh_devices is the mesh width OFFERED to fleets — the shard
-    # request clamped to the visible devices (0 = not sharded); each
-    # fleet may still use fewer devices, since the per-fleet mesh is
-    # capped at half its padded member count (see step._resolve_mesh)
+    # mesh_devices is the mesh width fleets run on (0 = not sharded)
     engine: str = "vectorized"
     mesh_devices: int = 0
 
@@ -474,9 +472,10 @@ def _run_grid(base: SimConfig,
     mesh with ``shard_map`` (``repro.sim.shard``): cells agreeing on
     every config knob except their workload (seeds AND scenarios) run
     as ONE SPMD program, ``mesh`` devices wide (None = all visible).
-    Per-cell results stay bit-identical to ``engine="scan"``.  With a
-    single visible device (CPU without forced host devices) the call
-    gracefully falls back to ``scan``.
+    Per-cell results stay bit-identical to ``engine="scan"``.  With
+    ``mesh=None`` and a single visible device (CPU without forced host
+    devices) the call runs ``scan`` and says so; an explicit ``mesh``
+    wider than the visible devices is a ``ValueError``.
 
     ``forecast_diag`` attaches one rolling forecast-error record per
     (scenario, forecaster) pair in the grid — computed on series sampled
@@ -499,16 +498,20 @@ def _run_grid(base: SimConfig,
         raise ValueError("empty sweep grid")
     mesh_devices = 0
     if engine == "shard":
-        # graceful single-device fallback: a 1-wide mesh buys nothing
-        # over the vmapped cohort path, so don't pay its placement.
-        # An over-asking --mesh is clamped to the visible devices, NOT
-        # an error — the fallback promise covers it
+        # a 1-wide mesh buys nothing over the vmapped cohort path, so
+        # don't pay its placement.  An explicit mesh the host cannot
+        # give is an error: a four-chip run that sees one chip must not
+        # pass as a one-chip run
         from repro.sim.shard import device_count
         want = device_count() if mesh is None else int(mesh)
-        want = max(1, min(want, device_count()))
+        if want > device_count():
+            raise ValueError(
+                f"mesh={want}: only {device_count()} device(s) visible "
+                "(on CPU set XLA_FLAGS="
+                "--xla_force_host_platform_device_count=N)")
         if want < 2:
-            print("# engine=shard: single device visible — falling back "
-                  "to engine=scan (on CPU set XLA_FLAGS="
+            print("# engine=shard: a 1-device mesh — running engine=scan "
+                  "(on CPU set XLA_FLAGS="
                   "--xla_force_host_platform_device_count=N)")
             engine = "scan"
         else:
@@ -874,7 +877,7 @@ def main(argv: Sequence[str] | None = None) -> SweepResult:
                          "seed loop; scan = device-resident fused tick "
                          "chunks with vmapped seed cohorts; shard = "
                          "scan fleets laid across a device mesh with "
-                         "shard_map (falls back to scan on one device)")
+                         "shard_map (runs scan on a 1-device mesh)")
     ap.add_argument("--chunk", type=int, default=32,
                     help="scan/shard engines: ticks per device call")
     ap.add_argument("--mesh", type=int, default=None,
@@ -924,6 +927,7 @@ def main(argv: Sequence[str] | None = None) -> SweepResult:
     args = ap.parse_args(argv)
     if args.seeds < 1:
         ap.error("--seeds must be >= 1")
+    enable_compile_cache()
 
     base = quick_base_config(args.apps, args.hosts, args.components)
     if args.target_q is not None:

@@ -1,5 +1,7 @@
 """Forecasting module tests (paper §3.1): accuracy, uncertainty,
 degenerate inputs, batching."""
+import dataclasses
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -104,6 +106,20 @@ def test_batched_matches_single():
                                    atol=1e-4)
 
 
+def test_gp_pallas_gram_matches_jnp():
+    """The GP fit differentiates through the Gram: with the Pallas kernel
+    (interpret mode here) its custom VJP must carry jax.grad, and the
+    forecast must match the jnp reference path."""
+    ys = jnp.asarray(np.stack([_series(k, n=24, seed=1)
+                               for k in ("const", "trend", "sine", "ar1")]))
+    cfg = GPConfig(history=10, max_patterns=10, opt_steps=10)
+    want = GPForecaster(cfg).forecast_batch(ys, 3)
+    got = GPForecaster(dataclasses.replace(cfg, impl="pallas")
+                       ).forecast_batch(ys, 3)
+    np.testing.assert_allclose(got.mean, want.mean, rtol=1e-3, atol=1e-3)
+    np.testing.assert_allclose(got.var, want.var, rtol=1e-3, atol=1e-3)
+
+
 @settings(max_examples=10, deadline=None)
 @given(data=st.lists(st.floats(-100, 100), min_size=25, max_size=40))
 def test_forecasters_never_nan(data):
@@ -113,3 +129,39 @@ def test_forecasters_never_nan(data):
         assert np.isfinite(np.asarray(fc.mean)).all()
         assert np.isfinite(np.asarray(fc.var)).all()
         assert (np.asarray(fc.var) >= 0).all()
+
+
+@pytest.mark.parametrize("model", [
+    GPForecaster(GPConfig(opt_steps=2)), ARIMAForecaster()],
+    ids=["gp", "arima"])
+def test_forecast_batch_runs_one_tile_program(model):
+    """Every row is forecast in the same TILE_ROWS-row program, whatever
+    the batch: 40 rows, 8 rows and a (2, 20) cohort vmap all lower to a
+    loop over (16, T) tiles (a TPU rounds by batch size, so the engines'
+    bucketed / cohort / fleet identities need one tile shape), and the
+    rows come back as the untiled vmap computes them."""
+    import jax
+
+    from repro.core.forecast.base import TILE_ROWS
+    rng = np.random.default_rng(0)
+    w = rng.uniform(0.5, 4.0, (40, 24)).astype(np.float32)
+    v = np.ones((40, 24), bool)
+    v[::3, :6] = False
+
+    def fc(w, v):
+        return model.forecast_batch(w, 3, valid=v)
+
+    tile = f"f32[{TILE_ROWS},24]"
+    for jaxpr in (jax.make_jaxpr(fc)(w, v), jax.make_jaxpr(fc)(w[:8], v[:8]),
+                  jax.make_jaxpr(jax.vmap(fc))(w.reshape(2, 20, 24),
+                                               v.reshape(2, 20, 24))):
+        text = str(jaxpr)
+        assert tile in text and "f32[40,24]" not in text.split("scan[")[-1]
+    full = jax.jit(fc)(w, v)
+    cohort = jax.jit(jax.vmap(fc))(w.reshape(2, 20, 24), v.reshape(2, 20, 24))
+    plain = jax.jit(jax.vmap(lambda w, v: model.forecast(w, 3, valid=v)))(
+        w[:TILE_ROWS], v[:TILE_ROWS])
+    np.testing.assert_array_equal(cohort.mean.reshape(40, 3), full.mean)
+    np.testing.assert_array_equal(cohort.var.reshape(40, 3), full.var)
+    np.testing.assert_array_equal(plain.mean, full.mean[:TILE_ROWS])
+    np.testing.assert_array_equal(plain.var, full.var[:TILE_ROWS])

@@ -9,7 +9,7 @@ from conftest import optional_hypothesis
 
 given, settings, st = optional_hypothesis()
 
-from repro.kernels import ops, ref
+from repro.kernels import fixed_order, ops, ref
 
 KEY = jax.random.PRNGKey(0)
 
@@ -30,6 +30,47 @@ def test_gram_matches_ref(kind, m, n, d):
     got = ops.gram(xa, xb, 0.7, 1.3, kind=kind, impl="pallas")
     want = ref.gram(xa, xb, 0.7, 1.3, kind=kind)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("kind", ["exp", "rbf"])
+def test_gram_grad_pallas_matches_jnp(kind):
+    """jax.grad through the Pallas Gram (its custom VJP) equals jax.grad
+    through the jnp reference, w.r.t. both pattern sets and both
+    hyper-parameters — the GP evidence loss needs exactly this."""
+    k1, k2 = jax.random.split(KEY)
+    xa = jax.random.normal(k1, (10, 11), jnp.float32)
+    xb = jax.random.normal(k2, (7, 11), jnp.float32)
+    w = jax.random.normal(KEY, (10, 7), jnp.float32)
+
+    def loss(impl):
+        return lambda a, b, ell, sf: (
+            w * ops.gram(a, b, ell, sf, kind=kind, impl=impl)).sum()
+
+    args = (xa, xb, jnp.float32(0.7), jnp.float32(1.3))
+    got = jax.grad(loss("pallas"), argnums=(0, 1, 2, 3))(*args)
+    want = jax.grad(loss("jnp"), argnums=(0, 1, 2, 3))(*args)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(g, r, rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("n", [1, 2, 3, 10, 11, 24])
+def test_tree_sum_is_a_fixed_pairwise_tree(n):
+    """tree_sum adds halves until one remains, padding an odd length
+    with an exact zero, along any axis: the same f32 adds as this loop."""
+    x = np.asarray(jax.random.normal(KEY, (3, n)), np.float32)
+
+    def pairwise(v):
+        v = list(v)
+        while len(v) > 1:
+            if len(v) % 2:
+                v.append(np.float32(0.0))
+            h = len(v) // 2
+            v = [a + b for a, b in zip(v[:h], v[h:])]
+        return v[0]
+
+    want = np.asarray([pairwise(r) for r in x], np.float32)
+    np.testing.assert_array_equal(fixed_order.tree_sum(x, 1), want)
+    np.testing.assert_array_equal(fixed_order.tree_sum(x.T, 0), want)
 
 
 @pytest.mark.parametrize("kind", ["exp", "rbf"])

@@ -15,6 +15,7 @@ The device-resident engine's contracts, in order of strength:
     ``tests/test_sweep.py`` and is unaffected by any of this.
 """
 import dataclasses
+import math
 
 import numpy as np
 import pytest
@@ -34,12 +35,7 @@ BASE = SimConfig(cluster=CL, workload=WL, max_ticks=3000)
 
 
 def _results_equal(a, b) -> bool:
-    return (a.summary() == b.summary()
-            and a.turnaround == b.turnaround
-            and a.failed_apps == b.failed_apps
-            and a.slack_cpu == b.slack_cpu and a.slack_mem == b.slack_mem
-            and a.util_cpu == b.util_cpu and a.util_mem == b.util_mem
-            and a.n_running == b.n_running)
+    return a.first_difference(b) is None
 
 
 # ----------------------------------------------------------------------
@@ -103,6 +99,60 @@ def test_cohort_rejects_mismatched_shapes():
                                         n_apps=WL.n_apps + 1))]
     with pytest.raises(ValueError, match="shape"):
         run_cohort_scan(cfg, [0, 1], wls=wls)
+
+
+# Float sums the compiler may order: a one-hot sum (exact in any order)
+# and the pattern-set cotangents of the Pallas Gram's VJP, which the GP,
+# differentiating only its hyper-parameters, never reads.
+_COMPILER_ORDERED_OK = {("fixed_order.py", "onehot_sum"), ("ops.py", "gram")}
+_SUMMING = {"reduce_sum", "reduce_prod", "dot_general", "cumsum", "cumprod",
+            "cumlogsumexp", "cholesky", "triangular_solve"}
+
+
+@pytest.mark.parametrize("variant", ["bucketed", "unbucketed", "leap"])
+def test_gp_tick_sums_in_a_fixed_order(monkeypatch, variant):
+    """The paper's GP tick, with the Pallas Gram selected as on a TPU,
+    holds no float sum whose order is left to the compiler outside
+    ``_COMPILER_ORDERED_OK``.  A TPU compiler orders a ``reduce`` (and a
+    library Cholesky, solve or matvec) by layout, which differs between
+    a solo program and a vmapped cohort or sharded fleet, so the members
+    would part from their solo runs in the last bit there; the CPU runs
+    of this file cannot see that."""
+    import jax
+    import jax.numpy as jnp
+    from jax._src import source_info_util
+
+    from repro.kernels import ops
+    from repro.sim import step
+    from repro.sim.state import init_state
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    cfg = dataclasses.replace(SimConfig(), leap=variant == "leap")
+    wl = generate(dataclasses.replace(cfg.workload, n_apps=8))
+    tr = step._device_trace([wl], batched=False)
+    st = init_state(cfg, wl.n_apps, wl.max_components)
+    bucket = 8 if variant == "bucketed" else None
+    run_chunk, _ = step._chunk_body(cfg, step._make_model(cfg), 1, bucket)
+    args = (tr, st, jnp.int32(1)) if cfg.leap else (tr, st)
+    bad = set()
+
+    def walk(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":   # the kernel's own order
+                continue
+            x = e.invars[0].aval if e.invars else None
+            if (e.primitive.name in _SUMMING
+                    and jnp.issubdtype(x.dtype, jnp.floating)
+                    and not (e.primitive.name == "reduce_sum" and math.prod(
+                        x.shape[a] for a in e.params["axes"]) <= 2)):
+                f = source_info_util.user_frame(e.source_info.traceback)
+                where = (f.file_name.rsplit("/", 1)[-1], f.function_name)
+                if where not in _COMPILER_ORDERED_OK:
+                    bad.add((e.primitive.name, x.shape, *where, f.start_line))
+            for sub in jax.core.jaxprs_in_params(e.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(run_chunk)(*args).jaxpr)
+    assert not bad, sorted(bad)
 
 
 # ----------------------------------------------------------------------

@@ -15,7 +15,8 @@ Contracts, in order of strength:
     subprocess test keeps the multi-device path exercised in every
     tier-1 run;
   * the sweep's ``engine="shard"`` groups cells into fleets (cells x
-    seeds, across scenarios) and falls back to ``scan`` on one device.
+    seeds, across scenarios); with no mesh on one device it runs
+    ``scan``, and a mesh wider than the visible devices is an error.
 """
 import dataclasses
 import json
@@ -42,12 +43,7 @@ multi_device = pytest.mark.skipif(
 
 
 def _results_equal(a, b) -> bool:
-    return (a.summary() == b.summary()
-            and a.turnaround == b.turnaround
-            and a.failed_apps == b.failed_apps
-            and a.slack_cpu == b.slack_cpu and a.slack_mem == b.slack_mem
-            and a.util_cpu == b.util_cpu and a.util_mem == b.util_mem
-            and a.n_running == b.n_running)
+    return a.first_difference(b) is None
 
 
 # ----------------------------------------------------------------------
@@ -172,14 +168,16 @@ def test_sweep_shard_falls_back_to_scan_on_one_device(capsys):
     if jax.device_count() > 1:
         pytest.skip("fallback only triggers on a single device")
     base = quick_base_config(n_apps=20, n_hosts=3, seed=0)
-    # mesh=4 over-asks the single visible device: still a graceful
-    # fallback (clamped to the devices), never a ValueError
-    res = run_grid(base, axes={"policy": ["pessimistic"],
-                               "forecaster": ["persist"]},
-                   seeds=[0, 1], engine="shard", mesh=4)
+    axes = {"policy": ["pessimistic"], "forecaster": ["persist"]}
+    # mesh=4 over-asks the single visible device: an error, never a
+    # silent one-device run
+    with pytest.raises(ValueError, match="mesh=4"):
+        run_grid(base, axes=axes, seeds=[0, 1], engine="shard", mesh=4)
+    # mesh=None on one device runs the scan engine and says so
+    res = run_grid(base, axes=axes, seeds=[0, 1], engine="shard")
     assert res.engine == "scan"
     assert res.mesh_devices == 0
-    assert "falling back" in capsys.readouterr().out
+    assert "running engine=scan" in capsys.readouterr().out
 
 
 # ----------------------------------------------------------------------
@@ -231,3 +229,50 @@ def test_wide_mesh_bit_identity_subprocess():
             json.dumps(want.turnaround)), f"seed {seed}"
         assert got["summary"] == json.loads(
             json.dumps(want.summary())), f"seed {seed}"
+
+
+_CHILD_ONE_PER_DEVICE = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+import json
+import jax
+from repro.sim import ClusterConfig, SimConfig, WorkloadConfig
+from repro.sim.step import FLEET_AXIS, run_fleet_shard
+
+WL = WorkloadConfig(n_apps=12, max_components=4, max_runtime=900.0,
+                    mean_burst_gap=4.0, mean_long_gap=60.0, seed=3)
+cfg = SimConfig(cluster=ClusterConfig(n_hosts=2, max_running_apps=8),
+                workload=WL, max_ticks=1500,
+                policy="pessimistic", forecaster="persist")
+seeds = [0, 1, 2, 3]
+wide = run_fleet_shard(cfg, seeds, chunk=16, mesh=4)
+# the mesh=4 fleet's uploaded trace stays cached on its devices: read
+# its layout before the mesh=1 upload joins it
+rows = sorted({(len(a.sharding.device_set), a.sharding.shard_shape(a.shape)[0])
+               for a in jax.live_arrays()
+               if getattr(a.sharding, "mesh", None) is not None
+               and FLEET_AXIS in a.sharding.mesh.axis_names})
+narrow = run_fleet_shard(cfg, seeds, chunk=16, mesh=1)
+print(json.dumps({"rows": rows, "same": [
+    repr((a.summary(), sorted(a.turnaround.items()), a.util_cpu, a.util_mem,
+          a.slack_cpu, a.slack_mem, a.n_running))
+    == repr((b.summary(), sorted(b.turnaround.items()), b.util_cpu,
+             b.util_mem, b.slack_cpu, b.slack_mem, b.n_running))
+    for a, b in zip(wide, narrow)]}))
+"""
+
+
+def test_fleet_one_member_per_device_subprocess():
+    """A fleet of 4 on mesh=4 puts ONE member on each device (no cap on
+    the mesh width) and stays bit-identical per member to mesh=1."""
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+        filter(None, [os.path.join(os.path.dirname(__file__), "..", "src"),
+                      os.environ.get("PYTHONPATH", "")])))
+    out = subprocess.run([sys.executable, "-c", _CHILD_ONE_PER_DEVICE],
+                         env=env, capture_output=True, text=True,
+                         timeout=420)
+    assert out.returncode == 0, out.stderr[-2000:]
+    child = json.loads(out.stdout.splitlines()[-1])
+    assert child["rows"] == [[4, 1]]          # 4 devices, 1 row each
+    assert child["same"] == [True] * 4

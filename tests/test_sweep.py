@@ -18,12 +18,7 @@ BASE = SimConfig(cluster=CL, workload=WL, max_ticks=4000)
 
 
 def _results_equal(a, b) -> bool:
-    return (a.summary() == b.summary()
-            and a.turnaround == b.turnaround
-            and a.failed_apps == b.failed_apps
-            and a.slack_cpu == b.slack_cpu and a.slack_mem == b.slack_mem
-            and a.util_cpu == b.util_cpu and a.util_mem == b.util_mem
-            and a.n_running == b.n_running)
+    return a.first_difference(b) is None
 
 
 # ----------------------------------------------------------------------
@@ -189,3 +184,56 @@ def test_sweep_reference_engine_option():
     vec = run_grid(base, **kw)
     ref = run_grid(base, engine="reference", **kw)
     assert vec.cells[0]["summary"] == ref.cells[0]["summary"]
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """The entry points' persistent compile cache: the environment's
+    directory when set, else one fixed, git-ignored path in the
+    checkout (never a temporary or per-process name)."""
+    import pathlib
+
+    import jax
+
+    from repro.sim import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = pathlib.Path(compile_cache.enable_compile_cache())
+        assert path == compile_cache.CHECKOUT_CACHE
+        assert jax.config.jax_compilation_cache_dir == str(path)
+        root = pathlib.Path(__file__).resolve().parents[1]
+        assert path.parent == root
+        assert f"{path.name}/" in (root / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_cold_compiles_bypass_the_cache(tmp_path):
+    """A compile timed inside ``cold_compiles`` neither reads nor writes
+    the persistent cache; outside it, the cache takes the entry."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro.sim.compile_cache import cold_compiles
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    before = {k: getattr(jax.config, k) for k in keys}
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        compilation_cache.reset_cache()
+        with cold_compiles():
+            jax.jit(lambda x: jnp.sin(x) * 3.0)(jnp.ones(7)).block_until_ready()
+        assert not any(tmp_path.iterdir())
+        jax.jit(lambda x: jnp.cos(x) * 5.0)(jnp.ones(9)).block_until_ready()
+        assert any(tmp_path.iterdir())
+    finally:
+        for k, v in before.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
