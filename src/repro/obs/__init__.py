@@ -5,8 +5,10 @@ Spans device and host:
   * :mod:`repro.obs.rings` — device-side per-tick telemetry rings
     (``ObsState``, structurally absent when disabled) drained at chunk
     boundaries by the scan/shard engines;
-  * :mod:`repro.obs.trace` — host span tracing to Chrome trace-event /
-    Perfetto JSON for sweep-driver phases;
+  * :mod:`repro.obs.trace` — host spans of the sweep driver and the
+    chunk driver (bucket pick, chunk launch, wait for ``done``), to
+    Chrome trace-event / Perfetto JSON and, whenever a ``jax.profiler``
+    session is active, into the profiler's trace on the device's clock;
   * :mod:`repro.obs.metrics` — process metrics registry (counters /
     gauges / histograms) with JSONL + Prometheus-textfile export;
   * :mod:`repro.obs.timing` — the shared benchmark timers;

@@ -1,29 +1,39 @@
-"""Host-side span tracing: Chrome trace-event / Perfetto JSON.
+"""Host-side span tracing: Chrome trace-event / Perfetto JSON, and the
+``jax.profiler`` trace.
 
 A :class:`Tracer` collects "X" (complete) events — name, category,
 start timestamp, duration — from :func:`span` context managers placed
-around sweep-driver phases (trace build, jit compile, chunk execute,
-ring drain, per-combo cohorts).  :func:`tracing` installs a global
-tracer for a ``with`` region and writes the JSON on exit; when no
-tracer is installed every ``span`` is a shared no-op, so the
-instrumentation costs one dict lookup on the disabled path.
+around sweep-driver phases (trace build, chunk-driver bucket pick,
+launch and wait, jit compile, ring drain, per-combo cohorts).
+:func:`tracing` installs a global tracer for a ``with`` region and
+writes the JSON on exit.
 
-Load the output in ``chrome://tracing`` or https://ui.perfetto.dev.
+Whenever a ``jax.profiler`` session is active, every ``span`` also
+enters a ``jax.profiler.TraceAnnotation`` of the same name, its
+``args`` becoming the event's stats: the span then lands on the
+profiler's host plane, on the same clock as the device's operations.
+With neither a tracer nor a profiler session, a span is a shared no-op
+that costs a global read and the profiler's own active check; ``args``
+are encoded only for a session that is on.
+
+Load the Chrome JSON in ``chrome://tracing`` or https://ui.perfetto.dev.
 """
 from __future__ import annotations
 
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
 
 __all__ = ["Tracer", "span", "tracing", "current_tracer",
-           "validate_trace", "profiler_annotation"]
+           "validate_trace"]
 
 _NULL = contextlib.nullcontext()
 _lock = threading.Lock()
 _tracer: Tracer | None = None
+_annotation = None      # jax.profiler.TraceAnnotation, once jax is imported
 
 
 class Tracer:
@@ -84,10 +94,40 @@ def current_tracer() -> Tracer | None:
     return _tracer
 
 
+def _profiling():
+    """The profiler's annotation type while a ``jax.profiler`` session is
+    active, else None.  Without jax imported no session can be active,
+    so a process that never imports jax never imports it here."""
+    global _annotation
+    if _annotation is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation if _annotation.is_enabled() else None
+
+
 def span(name: str, cat: str = "sweep", args: dict | None = None):
-    """Span against the installed tracer, or a shared no-op context."""
+    """Span against the installed tracer and, while a ``jax.profiler``
+    session is active, the profiler's trace; a shared no-op context
+    when neither is on.  ``args`` is read when the span closes, so the
+    body may fill in what it learns (``_pick_bucket`` does)."""
     t = _tracer
-    return t.span(name, cat, args) if t is not None else _NULL
+    ann = _profiling()
+    if ann is None:
+        return t.span(name, cat, args) if t is not None else _NULL
+    return _annotated(t, ann, name, cat, args)
+
+
+@contextlib.contextmanager
+def _annotated(t, ann, name, cat, args):
+    a = ann(name)
+    with a, (t.span(name, cat, args) if t is not None else _NULL):
+        try:
+            yield
+        finally:
+            if args:
+                a.set_metadata(**args)
 
 
 @contextlib.contextmanager
@@ -108,17 +148,6 @@ def tracing(path: str | None = None):
             _tracer = None
         if path is not None:
             t.save(path)
-
-
-def profiler_annotation(name: str):
-    """Optional ``jax.profiler`` hook: returns a TraceAnnotation so obs
-    spans also show up in XLA profiler dumps, or a no-op context when
-    the profiler is unavailable (e.g. stripped minimal builds)."""
-    try:
-        import jax.profiler
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return contextlib.nullcontext()
 
 
 def validate_trace(doc) -> list[str]:

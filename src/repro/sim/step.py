@@ -252,7 +252,8 @@ def _bucketed_forecast(cfg, model, wins: Array, valid: Array,
     come back 0, which downstream masking (``ready2`` in
     ``_shaped_demands``, ``deploy`` in ``calib_begin``) never reads.
     The scatter-back is a one-hot masked sum, not ``.at[].set`` — XLA
-    CPU scatters serialize under the cohort vmap.
+    CPU scatters serialize under the cohort vmap.  The pass loop runs
+    under the tick's ``forecast`` scope.
 
     Returns (mean, var, n_pass), each over the full ``(2 * AC,)`` row
     space; ``n_pass * 2 * bucket`` is the rows the model actually
@@ -298,8 +299,9 @@ def _bucketed_forecast(cfg, model, wins: Array, valid: Array,
         return p + 1, mean, var
 
     z = jnp.zeros((2 * AC,), jnp.float32)
-    n_pass, mean, var = jax.lax.while_loop(
-        cond, body, (jnp.int32(0), z, z))
+    with jax.named_scope("forecast"):
+        n_pass, mean, var = jax.lax.while_loop(
+            cond, body, (jnp.int32(0), z, z))
     return mean, var, n_pass
 
 
@@ -313,7 +315,9 @@ def _shaped_demands(cfg, model, tr: DeviceTrace, st: SimState,
     Mirrors ``engine._shape_decisions``'s demand construction: running
     components default to their reservation; components past the grace
     period get ``clip(peak + beta, 0, request)`` — with the conformal
-    per-series scale replacing K2 when calibration is on."""
+    per-series scale replacing K2 when calibration is on.  The model
+    call runs under a ``forecast`` scope, nested in the tick's
+    ``shape``."""
     A, C = st.comp_running.shape
     AC = A * C
     gid = jnp.maximum(st.slot_gid, 0)
@@ -322,7 +326,8 @@ def _shaped_demands(cfg, model, tr: DeviceTrace, st: SimState,
     demand = jnp.where(run[:, :, None], req, 0.0)
 
     if cfg.forecaster == "oracle":
-        peaks = _oracle_peaks(tr, st, cfg.horizon, tick)
+        with jax.named_scope("forecast"):
+            peaks = _oracle_peaks(tr, st, cfg.horizon, tick)
         shaped = shaped_demand_raw(peaks, req, jnp.zeros_like(peaks),
                                    cfg.safeguard)
         return (jnp.where(run[:, :, None], shaped, demand), st,
@@ -348,7 +353,8 @@ def _shaped_demands(cfg, model, tr: DeviceTrace, st: SimState,
     valid = jnp.concatenate([vrow, vrow])
     fc_done = jnp.int32(0)
     if cfg.forecaster == "persist":
-        mean, var = persistence_peak(wins, valid)
+        with jax.named_scope("forecast"):
+            mean, var = persistence_peak(wins, valid)
     elif bucket is not None:
         mean, var, n_pass = _bucketed_forecast(
             cfg, model, wins, valid, ready, bucket)
@@ -364,7 +370,9 @@ def _shaped_demands(cfg, model, tr: DeviceTrace, st: SimState,
             z = jnp.zeros((2 * AC,), jnp.float32)
             return z, z
 
-        mean, var = jax.lax.cond(ready.any(), _model, _skip, (wins, valid))
+        with jax.named_scope("forecast"):
+            mean, var = jax.lax.cond(ready.any(), _model, _skip,
+                                     (wins, valid))
         fc_done = jnp.where(ready.any(), jnp.int32(2 * AC), jnp.int32(0))
 
     req_rows = jnp.concatenate([req[:, :, CPU].reshape(AC),
@@ -764,179 +772,202 @@ def fused_tick(cfg, model, tr: DeviceTrace, st: SimState,
     # nothing — only the clock needs explicit gating.  (The max_ticks
     # budget is enforced by the driver slicing the last chunk exactly,
     # so a truncated sim never executes ticks past its budget either.)
-    active = ~st.done.all()
-    t_prev = st.t
-    t = st.t + jnp.float32(tick)
-
-    # telemetry rings (repro.obs): static pytree-structure branch like
-    # `ctl` below.  Entry-of-tick counter snapshots turn the cumulative
-    # counters into per-tick DELTAS — raw sums only, never ratios, so
-    # the rings stay chunk-invariant (see ObsState docstring).
+    #
+    # Each phase runs under a ``jax.named_scope`` (``tick/<phase>`` in
+    # the compiled program's op_name metadata, which a profiler trace
+    # of the device attributes its operations by; the innermost phase
+    # counts, as `forecast` nests in `shape`); scopes change only
+    # metadata, never the program's results.
     rec = st.obs is not None
-    if rec:
-        oom0, fail0 = st.oom_kills, st.failure_events
-        pre0 = st.full_preemptions + st.partial_preemptions
-        cres0 = st.calib.resolved if st.calib is not None else None
-        cerr0 = st.calib.errors if st.calib is not None else None
-        obs_dem = None                       # shaped-demand sums, (2,)
-        obs_throttled = jnp.int32(0)
-        obs_credit = jnp.float32(0.0)
-
-    # 1. arrivals
-    new = ~st.arrived & (tr.submit <= t)
-    st = dataclasses.replace(st, arrived=st.arrived | new,
-                             queued=st.queued | new)
-
-    # 2. progress + completions (monitor resets accumulate across phases
-    # and apply once at end of tick — see _mon_reset)
     ctl = st.tenancy is not None          # static pytree-structure branch
-    done_before = st.done
-    st, resets = _completions(tr, st, t, tick)
-
-    # control-plane event accounting (mirrors HostControl.note_*): good
-    # events are completions + covered conformal resolutions, bad events
-    # are failures + miscoverage; `fail_t` tracks failures alone for the
-    # per-tenant failed counter.
-    if ctl:
-        Tn = cfg.control.max_tenants
-        comp_t = _tenant_counts(tr.tenant, st.done & ~done_before, Tn)
-        good_t = comp_t
-        bad_t = jnp.zeros((Tn,), jnp.int32)
-        fail_t = jnp.zeros((Tn,), jnp.int32)
-
-    # 3. monitor sampling
-    gid = jnp.maximum(st.slot_gid, 0)
-    prog = jnp.clip(st.work_done / tr.runtime[gid], 0.0, 1.0)
-    usage = _usage_at(tr, st, prog)
-    st = _record_monitor(st, usage)
-    if st.calib is not None:
-        rows = jnp.concatenate([usage[:, :, CPU].reshape(-1),
-                                usage[:, :, MEM].reshape(-1)])
-        grp = ctl and st.calib.group_resolved is not None
-        if grp:
-            gr0, ge0 = st.calib.group_resolved, st.calib.group_errors
-        st = dataclasses.replace(
-            st, calib=calib_observe(st.calib, rows,
-                                    jnp.tile(st.mon_count, 2),
-                                    cfg.calibration, active=active))
-        if grp:
-            derr = st.calib.group_errors - ge0
-            good_t = good_t + (st.calib.group_resolved - gr0) - derr
-            bad_t = bad_t + derr
-
-    # 4. shaping (static branch: the baseline policy never shapes).
-    # The engine skips this phase when no slot is occupied; here an
-    # empty slot table makes every sub-step a no-op (empty kill masks,
-    # all-zero allocations over an all-zero table), so no gate is needed.
-    fc_rows = fc_done = jnp.int32(0)
-    if cfg.policy != "baseline":
-        demand, st, fc_rows, fc_done = _shaped_demands(
-            cfg, model, tr, st, tick, bucket)
+    with jax.named_scope("tick"):
+        # telemetry rings (repro.obs): static pytree-structure branch
+        # like `ctl`.  Entry-of-tick counter snapshots turn the
+        # cumulative counters into per-tick DELTAS — raw sums only, never
+        # ratios, so the rings stay chunk-invariant (see ObsState).
         if rec:
-            obs_dem = demand.sum((0, 1))     # (2,) shaped-demand totals
-        prob = _shape_problem(cfg, tr, st, demand, t, host_cap)
-        dec = RAW_POLICIES[cfg.policy](prob)
-        st, usage, conflict, resets4 = _apply_decision(
-            cfg, tr, st, dec, usage)
-        st = dataclasses.replace(
-            st, failed=st.failed | conflict, queued=st.queued | conflict)
-        resets = resets | resets4
+            with jax.named_scope("metrics"):
+                oom0, fail0 = st.oom_kills, st.failure_events
+                pre0 = st.full_preemptions + st.partial_preemptions
+                cres0 = st.calib.resolved if st.calib is not None else None
+                cerr0 = st.calib.errors if st.calib is not None else None
+                obs_dem = None                   # shaped-demand sums, (2,)
+                obs_throttled = jnp.int32(0)
+                obs_credit = jnp.float32(0.0)
+
+        # 1. arrivals, with the tick's clock and its `active` gate
+        with jax.named_scope("arrivals"):
+            active = ~st.done.all()
+            t_prev = st.t
+            t = st.t + jnp.float32(tick)
+            new = ~st.arrived & (tr.submit <= t)
+            st = dataclasses.replace(st, arrived=st.arrived | new,
+                                     queued=st.queued | new)
+
+        # 2. progress + completions (monitor resets accumulate across
+        # phases and apply once at end of tick — see _mon_reset)
+        with jax.named_scope("completions"):
+            done_before = st.done
+            st, resets = _completions(tr, st, t, tick)
+
+            # control-plane event accounting (mirrors HostControl.note_*):
+            # good events are completions + covered conformal
+            # resolutions, bad events are failures + miscoverage;
+            # `fail_t` tracks failures alone for the per-tenant failed
+            # counter.
+            if ctl:
+                Tn = cfg.control.max_tenants
+                comp_t = _tenant_counts(tr.tenant, st.done & ~done_before, Tn)
+                good_t = comp_t
+                bad_t = jnp.zeros((Tn,), jnp.int32)
+                fail_t = jnp.zeros((Tn,), jnp.int32)
+
+        # 3. monitor sampling
+        with jax.named_scope("monitor"):
+            gid = jnp.maximum(st.slot_gid, 0)
+            prog = jnp.clip(st.work_done / tr.runtime[gid], 0.0, 1.0)
+            usage = _usage_at(tr, st, prog)
+            st = _record_monitor(st, usage)
+            if st.calib is not None:
+                rows = jnp.concatenate([usage[:, :, CPU].reshape(-1),
+                                        usage[:, :, MEM].reshape(-1)])
+                grp = ctl and st.calib.group_resolved is not None
+                if grp:
+                    gr0, ge0 = st.calib.group_resolved, st.calib.group_errors
+                st = dataclasses.replace(
+                    st, calib=calib_observe(st.calib, rows,
+                                            jnp.tile(st.mon_count, 2),
+                                            cfg.calibration, active=active))
+                if grp:
+                    derr = st.calib.group_errors - ge0
+                    good_t = good_t + (st.calib.group_resolved - gr0) - derr
+                    bad_t = bad_t + derr
+
+        # 4. shaping (static branch: the baseline policy never shapes).
+        # The engine skips this phase when no slot is occupied; here an
+        # empty slot table makes every sub-step a no-op (empty kill
+        # masks, all-zero allocations over an all-zero table), so no
+        # gate is needed.  The model call inside `_shaped_demands` runs
+        # under a nested `forecast` scope (`tick/shape/forecast`).
+        fc_rows = fc_done = jnp.int32(0)
+        if cfg.policy != "baseline":
+            with jax.named_scope("shape"):
+                demand, st, fc_rows, fc_done = _shaped_demands(
+                    cfg, model, tr, st, tick, bucket)
+                if rec:
+                    obs_dem = demand.sum((0, 1))  # (2,) shaped-demand totals
+                prob = _shape_problem(cfg, tr, st, demand, t, host_cap)
+                dec = RAW_POLICIES[cfg.policy](prob)
+                st, usage, conflict, resets4 = _apply_decision(
+                    cfg, tr, st, dec, usage)
+                st = dataclasses.replace(
+                    st, failed=st.failed | conflict,
+                    queued=st.queued | conflict)
+                resets = resets | resets4
+                if ctl:
+                    c4 = _tenant_counts(tr.tenant, conflict, Tn)
+                    fail_t = fail_t + c4
+                    bad_t = bad_t + c4
+
+        # 5. OS OOM (uncontrolled failures) — fails recorded + requeued
+        with jax.named_scope("oom"):
+            q5 = st.queued
+            st, usage, resets5 = _resolve_oom(tr, st, usage, host_cap)
+            if ctl:
+                oomed = _tenant_counts(tr.tenant, st.queued & ~q5, Tn)
+                fail_t = fail_t + oomed
+                bad_t = bad_t + oomed
+
+        # 6. scheduler: FIFO admission + elastic re-placement.  With the
+        # control plane on, a wDRF gate runs first: per-tenant dominant
+        # shares from the live allocation table decide which tenants may
+        # admit this tick (HostControl.gate, vectorized).
+        elig_app = None
         if ctl:
-            c4 = _tenant_counts(tr.tenant, conflict, Tn)
-            fail_t = fail_t + c4
-            bad_t = bad_t + c4
+            with jax.named_scope("tenancy"):
+                ten = st.tenancy
+                credit = (credit_step(ten.credit, good_t, bad_t,
+                                      cfg.control.credit_gamma,
+                                      cfg.control.credit_floor)
+                          if cfg.control.credit else ten.credit)
+                occ = st.slot_gid >= 0
+                tslot = jnp.where(
+                    occ, tr.tenant[jnp.maximum(st.slot_gid, 0)], -1)
+                oh_slot = tslot[:, None] == jnp.arange(Tn)[None, :]  # (A, T)
+                alloc_t = jnp.where(oh_slot[:, :, None],
+                                    st.alloc.sum(1)[:, None, :], 0.0).sum(0)
+                share = dominant_shares(alloc_t, host_cap.sum(0),
+                                        device_weights(cfg.control))
+                queued_t = _tenant_counts(tr.tenant, st.queued, Tn)
+                active_t = (share > 0) | (queued_t > 0)
+                if cfg.control.gate:
+                    slack = (jnp.float32(cfg.control.slack) * credit
+                             if cfg.control.credit
+                             else jnp.float32(cfg.control.slack))
+                    elig_t = gate_mask(share, active_t, slack)
+                else:
+                    elig_t = jnp.ones((Tn,), bool)
+                st = dataclasses.replace(st, tenancy=dataclasses.replace(
+                    ten, credit=credit,
+                    throttled=ten.throttled + jnp.where(elig_t, 0, queued_t),
+                    completed=ten.completed + comp_t,
+                    failed=ten.failed + fail_t,
+                    share_sum=(ten.share_sum
+                               + (share * active_t).astype(jnp.float32)),
+                    active_ticks=(ten.active_ticks
+                                  + active_t.astype(jnp.int32))))
+                elig_app = elig_t[jnp.clip(tr.tenant, 0, Tn - 1)]
+                q6 = st.queued
+                if rec:
+                    obs_throttled = jnp.where(elig_t, 0, queued_t).sum()
+                    obs_credit = credit_mean(credit, active_t)
+        with jax.named_scope("admit"):
+            q_admit = st.queued
+            st, resets6 = _admit_queued(cfg, tr, st, t, host_cap, elig_app)
+            if ctl:
+                st = dataclasses.replace(st, tenancy=dataclasses.replace(
+                    st.tenancy, admitted=st.tenancy.admitted
+                    + _tenant_counts(tr.tenant, q6 & ~st.queued, Tn)))
+        with jax.named_scope("elastic"):
+            st = _place_missing_elastic(tr, st, t, host_cap)
 
-    # 5. OS OOM (uncontrolled failures) — fails recorded + requeued
-    q5 = st.queued
-    st, usage, resets5 = _resolve_oom(tr, st, usage, host_cap)
-    if ctl:
-        oomed = _tenant_counts(tr.tenant, st.queued & ~q5, Tn)
-        fail_t = fail_t + oomed
-        bad_t = bad_t + oomed
+        # 7. metrics (raw sums; the ratios divide on the host at drain)
+        with jax.named_scope("metrics"):
+            st = _mon_reset(st, resets | resets5 | resets6)
+            used = _cluster_total(usage)
+            alloc = _cluster_total(jnp.where(st.comp_running[:, :, None],
+                                             st.alloc, 0.0))
+            metrics = TickMetrics(
+                valid=active,
+                n_running=(st.slot_gid >= 0).sum().astype(jnp.int32),
+                used_cpu=used[CPU], used_mem=used[MEM],
+                alloc_cpu=alloc[CPU], alloc_mem=alloc[MEM],
+                forecast_rows=fc_rows, forecast_rows_done=fc_done,
+                lead=jnp.int32(0) if lead is None else lead)
 
-    # 6. scheduler: FIFO admission + elastic re-placement.  With the
-    # control plane on, a wDRF gate runs first: per-tenant dominant
-    # shares from the live allocation table decide which tenants may
-    # admit this tick (HostControl.gate, vectorized).
-    elig_app = None
-    if ctl:
-        ten = st.tenancy
-        credit = (credit_step(ten.credit, good_t, bad_t,
-                              cfg.control.credit_gamma,
-                              cfg.control.credit_floor)
-                  if cfg.control.credit else ten.credit)
-        occ = st.slot_gid >= 0
-        tslot = jnp.where(occ, tr.tenant[jnp.maximum(st.slot_gid, 0)], -1)
-        oh_slot = tslot[:, None] == jnp.arange(Tn)[None, :]       # (A, T)
-        alloc_t = jnp.where(oh_slot[:, :, None],
-                            st.alloc.sum(1)[:, None, :], 0.0).sum(0)
-        share = dominant_shares(alloc_t, host_cap.sum(0),
-                                device_weights(cfg.control))
-        queued_t = _tenant_counts(tr.tenant, st.queued, Tn)
-        active_t = (share > 0) | (queued_t > 0)
-        if cfg.control.gate:
-            slack = (jnp.float32(cfg.control.slack) * credit
-                     if cfg.control.credit
-                     else jnp.float32(cfg.control.slack))
-            elig_t = gate_mask(share, active_t, slack)
-        else:
-            elig_t = jnp.ones((Tn,), bool)
-        st = dataclasses.replace(st, tenancy=dataclasses.replace(
-            ten, credit=credit,
-            throttled=ten.throttled + jnp.where(elig_t, 0, queued_t),
-            completed=ten.completed + comp_t,
-            failed=ten.failed + fail_t,
-            share_sum=ten.share_sum + (share * active_t).astype(jnp.float32),
-            active_ticks=ten.active_ticks + active_t.astype(jnp.int32)))
-        elig_app = elig_t[jnp.clip(tr.tenant, 0, Tn - 1)]
-        q6 = st.queued
-        if rec:
-            obs_throttled = jnp.where(elig_t, 0, queued_t).sum()
-            obs_credit = credit_mean(credit, active_t)
-    q_admit = st.queued
-    st, resets6 = _admit_queued(cfg, tr, st, t, host_cap, elig_app)
-    if ctl:
-        st = dataclasses.replace(st, tenancy=dataclasses.replace(
-            st.tenancy, admitted=st.tenancy.admitted
-            + _tenant_counts(tr.tenant, q6 & ~st.queued, Tn)))
-    st = _place_missing_elastic(tr, st, t, host_cap)
-    st = _mon_reset(st, resets | resets5 | resets6)
+            if rec:
+                zero = jnp.int32(0)
+                st = dataclasses.replace(st, obs=obs_record(st.obs, active, {
+                    "used_cpu": used[CPU], "used_mem": used[MEM],
+                    "queue": st.queued.sum().astype(jnp.int32),
+                    "gap_cpu": (obs_dem[CPU] - used[CPU]
+                                if obs_dem is not None else jnp.float32(0.0)),
+                    "gap_mem": (obs_dem[MEM] - used[MEM]
+                                if obs_dem is not None else jnp.float32(0.0)),
+                    "oom": st.oom_kills - oom0,
+                    "fail": st.failure_events - fail0,
+                    "preempt": (st.full_preemptions + st.partial_preemptions
+                                - pre0),
+                    "admitted": (q_admit & ~st.queued).sum().astype(jnp.int32),
+                    "throttled": obs_throttled,
+                    "credit": obs_credit,
+                    "cov_resolved": (st.calib.resolved - cres0
+                                     if cres0 is not None else zero),
+                    "cov_errors": (st.calib.errors - cerr0
+                                   if cerr0 is not None else zero),
+                }, lead=lead))
 
-    # 7. metrics (raw sums; the ratios divide on the host at drain)
-    used = _cluster_total(usage)
-    alloc = _cluster_total(jnp.where(st.comp_running[:, :, None], st.alloc,
-                                     0.0))
-    metrics = TickMetrics(
-        valid=active,
-        n_running=(st.slot_gid >= 0).sum().astype(jnp.int32),
-        used_cpu=used[CPU], used_mem=used[MEM],
-        alloc_cpu=alloc[CPU], alloc_mem=alloc[MEM],
-        forecast_rows=fc_rows, forecast_rows_done=fc_done,
-        lead=jnp.int32(0) if lead is None else lead)
-
-    if rec:
-        zero = jnp.int32(0)
-        st = dataclasses.replace(st, obs=obs_record(st.obs, active, {
-            "used_cpu": used[CPU], "used_mem": used[MEM],
-            "queue": st.queued.sum().astype(jnp.int32),
-            "gap_cpu": (obs_dem[CPU] - used[CPU]
-                        if obs_dem is not None else jnp.float32(0.0)),
-            "gap_mem": (obs_dem[MEM] - used[MEM]
-                        if obs_dem is not None else jnp.float32(0.0)),
-            "oom": st.oom_kills - oom0,
-            "fail": st.failure_events - fail0,
-            "preempt": (st.full_preemptions + st.partial_preemptions
-                        - pre0),
-            "admitted": (q_admit & ~st.queued).sum().astype(jnp.int32),
-            "throttled": obs_throttled,
-            "credit": obs_credit,
-            "cov_resolved": (st.calib.resolved - cres0
-                             if cres0 is not None else zero),
-            "cov_errors": (st.calib.errors - cerr0
-                           if cerr0 is not None else zero),
-        }, lead=lead))
-
-    st = dataclasses.replace(st, t=jnp.where(active, t, t_prev))
+            st = dataclasses.replace(st, t=jnp.where(active, t, t_prev))
     return st, metrics
 
 
@@ -966,39 +997,45 @@ def fused_leap(cfg, model, tr: DeviceTrace, st: SimState, left: Array,
     a zero obs column) so truncated histories match uniform ones.
 
     Returns (state, left', metrics): ``left' = left - lead - executed``.
+    The skip and the budget gating run under the ``tick/leap`` scope,
+    the executed tick under its own phases.
     """
     tick_f = jnp.float32(cfg.cluster.tick)
-    active = ~st.done.all() & (left > 0)
-    idle = active & (st.slot_gid < 0).all() & ~st.queued.any()
-    if st.calib is not None:
-        idle = idle & (st.calib.left == 0).all()
-    next_sub = jnp.min(jnp.where(st.arrived, jnp.inf, tr.submit))
+    leap = lambda: jax.named_scope("tick/leap")  # noqa: E731
+    with leap():
+        active = ~st.done.all() & (left > 0)
+        idle = active & (st.slot_gid < 0).all() & ~st.queued.any()
+        if st.calib is not None:
+            idle = idle & (st.calib.left == 0).all()
+        next_sub = jnp.min(jnp.where(st.arrived, jnp.inf, tr.submit))
 
-    def wcond(carry):
-        t_c, n = carry
-        return idle & (n < left) & (next_sub > t_c + tick_f)
+        def wcond(carry):
+            t_c, n = carry
+            return idle & (n < left) & (next_sub > t_c + tick_f)
 
-    def wbody(carry):
-        t_c, n = carry
-        return t_c + tick_f, n + 1
+        def wbody(carry):
+            t_c, n = carry
+            return t_c + tick_f, n + 1
 
-    t2, lead = jax.lax.while_loop(wcond, wbody, (st.t, jnp.int32(0)))
-    st = dataclasses.replace(st, t=t2)
-    run = active & (left - lead > 0)
+        t2, lead = jax.lax.while_loop(wcond, wbody, (st.t, jnp.int32(0)))
+        st = dataclasses.replace(st, t=t2)
+        run = active & (left - lead > 0)
     # the tick always executes (a vmapped cond would lower to a select
     # and run both branches anyway) and is discarded when the budget
     # ran out; `run` implies the tick's own `active` gate
     st2, m = fused_tick(cfg, model, tr, st, bucket=bucket, lead=lead)
-    st = jax.tree.map(lambda a, b: jnp.where(run, a, b), st2, st)
-    m = dataclasses.replace(m, valid=m.valid & run, lead=lead)
-    if st.obs is not None:
-        # budget exhausted mid-skip: the skipped ticks still happened —
-        # record them as one zero column standing for `lead` idle ticks
-        tail = ~run & (lead > 0)
-        st = dataclasses.replace(st, obs=obs_record(
-            st.obs, tail, {name: 0 for name, _ in RING_FIELDS},
-            lead=lead - 1))
-    return st, left - lead - run.astype(jnp.int32), m
+    with leap():
+        st = jax.tree.map(lambda a, b: jnp.where(run, a, b), st2, st)
+        m = dataclasses.replace(m, valid=m.valid & run, lead=lead)
+        if st.obs is not None:
+            # budget exhausted mid-skip: the skipped ticks still happened
+            # — record them as one zero column standing for `lead` idle
+            # ticks
+            tail = ~run & (lead > 0)
+            st = dataclasses.replace(st, obs=obs_record(
+                st.obs, tail, {name: 0 for name, _ in RING_FIELDS},
+                lead=lead - 1))
+        return st, left - lead - run.astype(jnp.int32), m
 
 
 # ----------------------------------------------------------------------
@@ -1055,48 +1092,61 @@ def _device_trace(wls, batched: bool, *, pad_to: int | None = None,
     return tr
 
 
-def _timed_first_call(fn, metric: str):
-    """Wrap a fresh jitted chunk fn so its FIRST call — which traces and
-    compiles synchronously before dispatching — is measured: the wall
-    feeds a ``repro.obs`` histogram (manifests snapshot it) and a
-    ``jit_compile`` trace span.  Later calls pass straight through;
-    ``wrapped.__wrapped__`` is the jitted fn."""
-    holder = {"first": True}
+def _chunk_launcher(fn, metric: str, bucket: int | None, ticks: int):
+    """Wrap a fresh jitted chunk fn.  Every call is a ``sim.chunk_launch``
+    span, the enqueue (args ``bucket``, ``ticks``, ``members`` and ``n``,
+    this program's launch count, which the driver's ``sim.chunk_wait``
+    of the same chunk repeats).  The FIRST call — which traces and
+    compiles synchronously before dispatching — is also measured: the
+    wall feeds a ``repro.obs`` histogram (manifests snapshot it) and a
+    ``jit_compile`` span.  ``wrapped.__wrapped__`` is the jitted fn,
+    ``wrapped.launches`` the calls so far."""
 
     @functools.wraps(fn)
-    def wrapped(*args):
-        if holder["first"]:
-            holder["first"] = False
+    def wrapped(tr, st, *rest):
+        wrapped.launches += 1
+        n = wrapped.launches
+        args = {"bucket": bucket, "ticks": ticks,
+                "members": st.t.shape[0] if st.t.ndim else 1, "n": n}
+        with span("sim.chunk_launch", cat="execute", args=args):
+            if n > 1:
+                return fn(tr, st, *rest)
             with span("jit_compile", cat="compile",
                       args={"metric": metric}):
                 t0 = time.perf_counter()
-                out = fn(*args)
+                out = fn(tr, st, *rest)
             REGISTRY.histogram(metric).observe(time.perf_counter() - t0)
             return out
-        return fn(*args)
 
+    wrapped.launches = 0
     return wrapped
 
 
 def _chunk_body(cfg, model, chunk: int, bucket):
     """The (un-vmapped) chunk step body: a ``lax.scan`` over
     :func:`fused_tick` (uniform) or :func:`fused_leap` (the tick budget
-    then rides in the carry)."""
+    then rides in the carry).
+
+    The function's name, ``tick_chunk``, names the jitted program
+    (``jit_tick_chunk``), and with it the key of JAX's persistent
+    compilation cache, which ignores op_name metadata: a program cached
+    before the tick's phases had named scopes cannot stand in for one
+    that has them."""
     if cfg.leap:
-        def run_chunk(tr, st, left):
+        def tick_chunk(tr, st, left):
             def body(carry, _):
                 s, l, m = fused_leap(cfg, model, tr, *carry, bucket=bucket)
                 return (s, l), m
             (st, left), ms = jax.lax.scan(body, (st, left), None,
                                           length=chunk)
             return st, left, ms
-        return run_chunk, (1, 2)
+        return tick_chunk, (1, 2)
 
-    def run_chunk(tr, st):
+    def tick_chunk(tr, st):
         def body(s, _):
             return fused_tick(cfg, model, tr, s, bucket=bucket)
         return jax.lax.scan(body, st, None, length=chunk)
-    return run_chunk, (1,)
+    return tick_chunk, (1,)
 
 
 # distinct (cfg-key, bucket) jit-cache entries created by the bucketed
@@ -1119,8 +1169,9 @@ def _chunk_fn(cfg, chunk: int, shapes, cohort: bool,
         run_chunk, donate = _chunk_body(cfg, model, chunk, bucket)
         if cohort:
             run_chunk = jax.vmap(run_chunk)
-        fn = _CHUNK_CACHE[key] = _timed_first_call(
-            jax.jit(run_chunk, donate_argnums=donate), "scan.compile_s")
+        fn = _CHUNK_CACHE[key] = _chunk_launcher(
+            jax.jit(run_chunk, donate_argnums=donate), "scan.compile_s",
+            bucket, chunk)
         if bucket is not None:
             _note_bucket_entry(key)
     return fn
@@ -1158,20 +1209,25 @@ def _pick_bucket(cfg, st) -> int | None:
     growth within the chunk is absorbed by extra ``_bucketed_forecast``
     passes, not a bigger bucket, so the choice affects performance only
     — never results.  ``None`` (the full-batch path) when the bucket
-    would cover the whole table anyway."""
-    mc = np.asarray(st.mon_count)
-    AC = mc.shape[-1]
-    run = ((np.asarray(st.slot_gid) >= 0)[..., None]
-           & np.asarray(st.comp_running)).reshape(mc.shape)
-    n = int((run & (mc >= cfg.grace)).sum(-1).max())
-    b = _BUCKET_MIN
-    while b < n:
-        b *= 2
-    if b >= AC:
-        return None
-    REGISTRY.counter("forecast.bucket_chunks", bucket=str(2 * b)).inc()
-    REGISTRY.histogram("forecast.bucket_occupancy",
-                       bucket=str(2 * b)).observe(n / b)
+    would cover the whole table anyway.
+
+    A ``sim.pick_bucket`` span covers the host work (the copies of the
+    state's slot and monitor columns); its args are the choice
+    (``bucket``, rows per resource), ``ready`` (the largest member's
+    ready-row count n) and ``members``."""
+    args = {}
+    with span("sim.pick_bucket", cat="execute", args=args):
+        mc = np.asarray(st.mon_count)
+        AC = mc.shape[-1]
+        run = ((np.asarray(st.slot_gid) >= 0)[..., None]
+               & np.asarray(st.comp_running)).reshape(mc.shape)
+        n = int((run & (mc >= cfg.grace)).sum(-1).max())
+        b = _BUCKET_MIN
+        while b < n:
+            b *= 2
+        b = None if b >= AC else b
+        args.update(bucket=b, ready=n,
+                    members=mc.shape[0] if mc.ndim > 1 else 1)
     return b
 
 
@@ -1201,6 +1257,11 @@ def _drive_chunks(cfg, chunk: int, fn_for_size, tr, st):
     When telemetry rings are present the host drains them at every
     chunk boundary (returned ``RingDrain``; ``None`` when obs is off),
     which is why ring capacity must cover a whole chunk.
+
+    Spans (``repro.obs.span``): ``sim.pick_bucket``, then the chunk
+    function's ``sim.chunk_launch`` (the enqueue), then
+    ``sim.chunk_wait`` around the read of ``done``, which returns once
+    the chunk has run: how long the host waited for it.
     """
     drain = _ring_drain(cfg, chunk, st)
     bucketing = _bucketed(cfg)
@@ -1209,16 +1270,17 @@ def _drive_chunks(cfg, chunk: int, fn_for_size, tr, st):
     while remaining > 0:
         size = min(chunk, remaining)
         fn = fn_for_size(size, _pick_bucket(cfg, st) if bucketing else None)
-        with span("chunk", cat="execute", args={"ticks": size}):
-            st, ms = fn(tr, st)
+        st, ms = fn(tr, st)                # the sim.chunk_launch span
+        # np.asarray, not st.done.all(): the fleet state is sharded
+        # across devices and the host-side gather is the cheap form
+        with span("sim.chunk_wait", cat="execute", args={"n": fn.launches}):
+            done = bool(np.asarray(st.done).all())
         parts.append(ms)
         remaining -= size
         if drain is not None:
             with span("ring_drain", cat="drain"):
                 drain.drain(st.obs)
-        # np.asarray, not st.done.all(): the fleet state is sharded
-        # across devices and the host-side gather is the cheap form
-        if bool(np.asarray(st.done).all()):
+        if done:
             break
     return st, parts, drain
 
@@ -1237,13 +1299,13 @@ def _drive_chunks_leap(cfg, chunk: int, fn_for_size, tr, st):
     parts = []
     while True:
         fn = fn_for_size(chunk, _pick_bucket(cfg, st) if bucketing else None)
-        with span("chunk", cat="execute", args={"ticks": chunk}):
-            st, left, ms = fn(tr, st, left)
+        st, left, ms = fn(tr, st, left)    # the sim.chunk_launch span
+        with span("sim.chunk_wait", cat="execute", args={"n": fn.launches}):
+            done = np.asarray(st.done)
         parts.append(ms)
         if drain is not None:
             with span("ring_drain", cat="drain"):
                 drain.drain(st.obs)
-        done = np.asarray(st.done)
         if bool(np.all(done.all(axis=-1) | (np.asarray(left) <= 0))):
             break
     return st, parts, drain
@@ -1377,8 +1439,9 @@ def _shard_chunk_fn(cfg, chunk: int, shapes, mesh,
                                 in_specs=(spec,) * n_args,
                                 out_specs=(spec,) * n_args,
                                 check_vma=False)
-        fn = _CHUNK_CACHE[key] = _timed_first_call(
-            jax.jit(sharded, donate_argnums=donate), "shard.compile_s")
+        fn = _CHUNK_CACHE[key] = _chunk_launcher(
+            jax.jit(sharded, donate_argnums=donate), "shard.compile_s",
+            bucket, chunk)
         if bucket is not None:
             _note_bucket_entry(key)
     return fn
