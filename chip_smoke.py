@@ -21,7 +21,11 @@ and every phase checks what comes out:
     counters);
   * the Pallas Gram against ``ref.gram`` on the chip, and the GP and
     ARIMA forecasts on the chip against the same call on the host CPU,
-    within the tolerances stated below.
+    within the tolerances stated below;
+  * a 4-seed cohort chunk of the bucketed GP path after
+    ``PACK_AFTER`` chunks: the tile loop packing the cohort's ready rows into
+    the fewest tiles == the same chunk with every tile run
+    (``EveryTile``), state and per-tick totals bit for bit.
 
 ``--chips 4`` runs only the sharded fleet: ``run_fleet_shard`` over 8
 seeds on a 4-device mesh against ``mesh=1``, member by member, and
@@ -58,6 +62,9 @@ CHUNK = 32
 # then keeps its first bucket and absorbs ready-row growth in extra
 # passes, so each variant compiles one program
 SLICE_TICKS = 300
+# chunks run before the packing check's chunk: most slots are then past
+# the grace period, and members need different numbers of passes
+PACK_AFTER = 4
 
 GRAM_BATCH = 256        # GP rows per Gram check (a forecast bucket)
 # Pallas Gram vs ref.gram, both on the chip.  Off the diagonal the two
@@ -359,6 +366,61 @@ def slice_comparisons(sm: Smoke) -> None:
              f"util_mem ok {util_ok}; first differing tick {split}")
 
 
+class EveryTile:
+    """The model with its packed batches run as plain ones: the tile loop
+    runs every tile (``forecast.base.batched``) and counts none."""
+
+    def __init__(self, model):
+        self.model = model
+
+    def forecast_packed(self, windows, horizon, *, valid=None, live):
+        import jax.numpy as jnp
+        return (self.model.forecast_batch(windows, horizon, valid=valid),
+                jnp.int32(0))
+
+
+def packed_tiles(sm: Smoke) -> None:
+    """A 4-seed GP cohort chunk: packed tiles == every tile, bit for bit."""
+    import jax
+    import numpy as np
+
+    from repro.sim import step
+    from repro.sim.scenarios.registry import build_trace
+    from repro.sim.state import init_state
+    cfg = dataclasses.replace(base_config(), max_ticks=FULL_TICKS)
+    wls = [build_trace(dataclasses.replace(cfg.workload, seed=s))
+           for s in COHORT_SEEDS]
+    tr = step._device_trace(wls, batched=True)
+    st = init_state(cfg, wls[0].n_apps, wls[0].max_components,
+                    batch=len(wls))
+    shapes = step._shapes_key(wls[0], cfg)
+    for _ in range(PACK_AFTER):
+        st, _ = step._chunk_fn(cfg, CHUNK, shapes, True,
+                               step._pick_bucket(cfg, st))(tr, st)
+    bucket = step._pick_bucket(cfg, st)
+    every = jax.jit(jax.vmap(step._chunk_body(
+        cfg, EveryTile(step._make_model(cfg)), CHUNK, bucket)[0]))
+    st_e, ms_e = jax.device_get(every(tr, st))
+    # the engine's own program (it donates the state, so it runs last)
+    st_p, ms_p = jax.device_get(
+        step._chunk_fn(cfg, CHUNK, shapes, True, bucket)(tr, st))
+    bad = [k for k, (a, b) in enumerate(zip(jax.tree.leaves(st_p),
+                                            jax.tree.leaves(st_e)))
+           if not np.array_equal(a, b, equal_nan=True)]
+    bad += [f.name for f in dataclasses.fields(ms_p)
+            if f.name != "forecast_tile_rows"
+            and not np.array_equal(getattr(ms_p, f.name),
+                                   getattr(ms_e, f.name))]
+    tiles = int(np.asarray(ms_p.forecast_tile_rows).max(axis=0).sum())
+    offered = int((len(wls) * np.asarray(ms_p.forecast_rows_done)
+                   .max(axis=0)).sum())
+    sm.check(f"cohort chunk from tick {PACK_AFTER * CHUNK}, bucket {bucket}: "
+             "packed tiles == every tile", not bad,
+             f"differing state leaves / tick totals {bad[:8]}; "
+             f"{int(np.asarray(ms_p.forecast_rows).sum())} ready rows in "
+             f"{tiles} tile rows run, {offered} rows offered")
+
+
 def fleet_on_mesh(sm: Smoke) -> None:
     """Sharded fleet: mesh=4 == mesh=1 per member, and what the mesh=4
     run placed spans 4 devices."""
@@ -406,6 +468,7 @@ def run_phases(sm: Smoke, chips: int) -> None:
     keys = sm.phase("full run", full_run)
     sm.phase("kernel in chunk program", kernel_in_chunk_program, keys)
     sm.phase("slice comparisons", slice_comparisons)
+    sm.phase("packed tiles", packed_tiles)
 
 
 def main(argv=None) -> int:

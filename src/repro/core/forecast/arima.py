@@ -32,7 +32,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from repro.core.forecast.base import Forecast, batched
+from repro.core.forecast.base import Forecast, batched, packed
 
 Array = jax.Array
 
@@ -221,3 +221,9 @@ class ARIMAForecaster:
         # independence is the contract the engines' bucketed/padded
         # batch paths rely on, so there is exactly one batching idiom
         return batched(self.forecast, windows, horizon, valid)
+
+    def forecast_packed(self, windows: Array, horizon: int, *,
+                        valid: Array | None = None, live: Array):
+        # forecast_batch for the ``live`` rows alone, in the fewest tiles:
+        # (Forecast, tile rows run) (repro.core.forecast.base.packed)
+        return packed(self.forecast, windows, horizon, valid, live)

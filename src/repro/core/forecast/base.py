@@ -84,6 +84,9 @@ class Forecaster(Protocol):
 # bit-identical across batch sizes only when every row is computed in a
 # program of one shape.  The engines' bucketed, cohort and sharded paths
 # rely on that.  16 is the smallest bucketed pass (2 x 8 rows).
+# ``batched`` runs every tile of its batch; ``packed`` runs only the
+# ceil(live rows / TILE_ROWS) tiles that hold the rows it is told are
+# live, each of them this same program.
 TILE_ROWS = 16
 
 
@@ -101,9 +104,25 @@ def batched(forecast_fn, windows: Array, horizon: int,
     return _tiled(fn)(windows, valid)
 
 
-def _tiled(row_fn):
-    @jax.custom_batching.custom_vmap
-    def run(*xs):
+def packed(forecast_fn, windows: Array, horizon: int, valid: Array | None,
+           live: Array) -> tuple[Forecast, Array]:
+    """``batched`` for a batch whose ``live`` ((B,) bool) rows alone are
+    read: the live rows of the whole fold are packed into the fewest
+    tiles and the other rows come back 0.  Returns ``(Forecast,
+    tile_rows)``, ``tile_rows`` the rows of the tiles that ran (i32, the
+    same for every member of the fold)."""
+    valid = jnp.ones(windows.shape, dtype=bool) if valid is None else valid
+    return _tiled(lambda w, v: forecast_fn(w, horizon, valid=v),
+                  pack=True)(live, windows, valid)
+
+
+def _tiled(row_fn, pack: bool = False):
+    """``row_fn`` over the rows of its arguments, ``TILE_ROWS`` rows at a
+    time.  With ``pack``, the first argument is the rows' ``live``
+    mask: the live rows move to the front (a stable argsort), one
+    ``while_loop`` runs the tiles that hold them, and each comes back to
+    its own row by an integer gather; the tile program is the same."""
+    def all_tiles(*xs):
         n = xs[0].shape[0]
         pad = -n % TILE_ROWS
         tiles = tuple(
@@ -113,15 +132,56 @@ def _tiled(row_fn):
         return jax.tree.map(lambda y: y.reshape((-1,) + y.shape[2:])[:n],
                             out)
 
+    def live_tiles(live, *xs):
+        n = live.shape[0]
+        order = jnp.argsort(~live, stable=True)
+        xs = [jnp.pad(x[order], [(0, -n % TILE_ROWS)] + [(0, 0)] * (x.ndim - 1))
+              for x in xs]
+        n_tiles = (live.sum(dtype=jnp.int32) + TILE_ROWS - 1) // TILE_ROWS
+        shapes = jax.eval_shape(jax.vmap(row_fn),
+                                *[x[:TILE_ROWS] for x in xs])
+        buf = jax.tree.map(
+            lambda s: jnp.zeros(xs[0].shape[:1] + s.shape[1:], s.dtype),
+            shapes)
+
+        def body(carry):
+            i, buf = carry
+            start = i * TILE_ROWS
+            y = jax.vmap(row_fn)(*[jax.lax.dynamic_slice_in_dim(
+                x, start, TILE_ROWS) for x in xs])
+            return i + 1, jax.tree.map(
+                lambda b, y: jax.lax.dynamic_update_slice_in_dim(
+                    b, y, start, 0), buf, y)
+
+        _, buf = jax.lax.while_loop(lambda c: c[0] < n_tiles, body,
+                                    (jnp.int32(0), buf))
+        # live row r sits at packed position (live rows before r)
+        pos = jnp.cumsum(live, dtype=jnp.int32) - 1
+
+        def back(y):
+            keep = live.reshape((n,) + (1,) * (y.ndim - 1))
+            return jnp.where(keep, y[jnp.maximum(pos, 0)], 0)
+        return jax.tree.map(back, buf), n_tiles * TILE_ROWS
+
+    run = jax.custom_batching.custom_vmap(live_tiles if pack else all_tiles)
+
     @run.def_vmap
     def _fold(axis_size, in_batched, *xs):
         xs = [x if b else jnp.broadcast_to(x, (axis_size,) + x.shape)
               for x, b in zip(xs, in_batched)]
         n = xs[0].shape[1]
+
+        def unfold(out):
+            out = jax.tree.map(
+                lambda y: y.reshape((axis_size, n) + y.shape[1:]), out)
+            return out, jax.tree.map(lambda _: True, out)
         out = run(*[x.reshape((axis_size * n,) + x.shape[2:]) for x in xs])
-        out = jax.tree.map(
-            lambda y: y.reshape((axis_size, n) + y.shape[1:]), out)
-        return out, jax.tree.map(lambda _: True, out)
+        if not pack:
+            return unfold(out)
+        fc, rows = out
+        fc, fc_batched = unfold(fc)
+        # the fold's tile rows: one count for every member
+        return (fc, rows), (fc_batched, False)
 
     return run
 

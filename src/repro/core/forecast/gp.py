@@ -35,7 +35,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.core.forecast.base import Forecast, batched
+from repro.core.forecast.base import Forecast, batched, packed
 from repro.kernels import ops as kops
 from repro.kernels.fixed_order import tree_sum
 
@@ -266,3 +266,9 @@ class GPForecaster:
         # independence is the contract the engines' bucketed/padded
         # batch paths rely on, so there is exactly one batching idiom
         return batched(self.forecast, windows, horizon, valid)
+
+    def forecast_packed(self, windows: Array, horizon: int, *,
+                        valid: Array | None = None, live: Array):
+        # forecast_batch for the ``live`` rows alone, in the fewest tiles:
+        # (Forecast, tile rows run) (repro.core.forecast.base.packed)
+        return packed(self.forecast, windows, horizon, valid, live)

@@ -231,10 +231,18 @@ class TickMetrics:
     # computes the whole padded batch, so ready/batch is the masked-rows
     # overhead the bucketed path exists to close)
     forecast_rows: Array  # () i32
-    # rows the forecast MODEL actually computed this tick: the full
-    # padded batch when it ran un-bucketed, passes x bucket batch under
-    # ragged bucketing, 0 for persist/oracle (no model call)
+    # rows OFFERED to the forecast model this tick: the full padded
+    # batch when it ran un-bucketed, this member's passes x bucket batch
+    # under ragged bucketing, 0 for persist/oracle (no model call)
     forecast_rows_done: Array  # () i32
+    # rows of the TILES the model's tile loop ran (forecast.base.TILE_ROWS
+    # each): only tiles that hold ready rows run, packed across the whole
+    # fold, so under a cohort vmap the member with the most passes holds
+    # the cohort's total.  Under the fleet's shard_map each device folds
+    # only its own members: there the count is that device's total, and
+    # the fleet's is the sum of the devices' maxima.  0 for
+    # persist/oracle/baseline
+    forecast_tile_rows: Array  # () i32
     # event-leap telemetry: provably-idle ticks the leap engine skipped
     # immediately BEFORE this step's tick (always 0 under the uniform
     # engine).  drain_results re-expands each step into `lead` all-zero

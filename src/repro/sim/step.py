@@ -255,9 +255,16 @@ def _bucketed_forecast(cfg, model, wins: Array, valid: Array,
     CPU scatters serialize under the cohort vmap.  The pass loop runs
     under the tick's ``forecast`` scope.
 
-    Returns (mean, var, n_pass), each over the full ``(2 * AC,)`` row
-    space; ``n_pass * 2 * bucket`` is the rows the model actually
-    computed (the ``rows_bucketed`` telemetry).
+    Each pass OFFERS the model ``2 * bucket`` rows and marks the ready
+    ones ``live``; the model's tile loop RUNS only the tiles that hold
+    live rows, packed across the whole fold (``forecast.base.packed``):
+    under the cohort ``vmap``, a member past its last pass and the
+    padding of every pass add no tiles.
+
+    Returns (mean, var, n_pass, tile_rows): mean and var over the full
+    ``(2 * AC,)`` row space; ``n_pass * 2 * bucket`` is the rows the
+    member's passes offered (the ``rows_bucketed`` telemetry),
+    ``tile_rows`` the rows of the tiles its passes ran for the fold.
     """
     AC = ready.shape[0]
     B = bucket
@@ -275,12 +282,14 @@ def _bucketed_forecast(cfg, model, wins: Array, valid: Array,
         return carry[0] * B < n_ready
 
     def body(carry):
-        p, mean, var = carry
+        p, mean, var, tiles = carry
         idx = jax.lax.dynamic_slice(order, (p * B,), (B,))
         in_pass = (jnp.arange(B) + p * B) < n_ready
         w2 = jnp.concatenate([wins[idx], wins[idx + AC]])
         v2 = jnp.concatenate([valid[idx], valid[idx + AC]])
-        fc = model.forecast_batch(w2, cfg.horizon, valid=v2)
+        fc, rows = model.forecast_packed(
+            w2, cfg.horizon, valid=v2,
+            live=jnp.concatenate([in_pass, in_pass]))
         peak, pvar = peak_over_horizon(fc)
         peak = peak.astype(jnp.float32)
         pvar = pvar.astype(jnp.float32)
@@ -296,21 +305,22 @@ def _bucketed_forecast(cfg, model, wins: Array, valid: Array,
 
         mean = mean + jnp.concatenate([back(peak[:B]), back(peak[B:])])
         var = var + jnp.concatenate([back(pvar[:B]), back(pvar[B:])])
-        return p + 1, mean, var
+        return p + 1, mean, var, tiles + rows
 
     z = jnp.zeros((2 * AC,), jnp.float32)
     with jax.named_scope("forecast"):
-        n_pass, mean, var = jax.lax.while_loop(
-            cond, body, (jnp.int32(0), z, z))
-    return mean, var, n_pass
+        n_pass, mean, var, tiles = jax.lax.while_loop(
+            cond, body, (jnp.int32(0), z, z, jnp.int32(0)))
+    return mean, var, n_pass, tiles
 
 
 def _shaped_demands(cfg, model, tr: DeviceTrace, st: SimState,
                     tick: float, bucket: int | None = None
-                    ) -> tuple[Array, SimState, Array, Array]:
+                    ) -> tuple[Array, SimState, Array, Array, Array]:
     """(A, C, 2) shaped demand table, updated calib state, the number of
-    forecast rows actually past the grace period this tick, and the rows
-    the forecast model computed for them (0 for persist/oracle).
+    forecast rows actually past the grace period this tick, the rows
+    offered to the forecast model for them, and the rows of the tiles
+    its tile loop ran (both 0 for persist/oracle).
 
     Mirrors ``engine._shape_decisions``'s demand construction: running
     components default to their reservation; components past the grace
@@ -331,7 +341,7 @@ def _shaped_demands(cfg, model, tr: DeviceTrace, st: SimState,
         shaped = shaped_demand_raw(peaks, req, jnp.zeros_like(peaks),
                                    cfg.safeguard)
         return (jnp.where(run[:, :, None], shaped, demand), st,
-                jnp.int32(0), jnp.int32(0))
+                jnp.int32(0), jnp.int32(0), jnp.int32(0))
 
     # forecast over the monitor rows (CPU rows then MEM rows); rows not
     # past the grace period are masked out of the demand afterwards.
@@ -339,40 +349,33 @@ def _shaped_demands(cfg, model, tr: DeviceTrace, st: SimState,
     # bucketed path: with ``bucket`` set (the driver's per-chunk choice)
     # the gp/arima model runs only over the ready rows, in
     # ceil(ready / bucket) passes of a fixed-shape batch.  Un-bucketed,
-    # the MODEL call is gated on any row being ready at all, which skips
-    # the model during warm-up/grace ticks and after global completion —
-    # but the gate only helps solo (non-vmapped) programs: under a
-    # cohort vmap the cond lowers to a select and both branches execute.
-    # The ready/computed gap either way is what the ``forecast_rows``
-    # telemetry measures (the gp block of BENCH_engine.json).
+    # the model is offered the whole table with the ready rows ``live``:
+    # its tile loop runs only the tiles that hold them (none during
+    # warm-up/grace ticks and after global completion, solo or under a
+    # cohort vmap).  The ready/offered gap either way is what the
+    # ``forecast_rows`` telemetry measures (the gp block of
+    # BENCH_engine.json).
     W = st.mon_buf.shape[1]
     ready = run.reshape(AC) & (st.mon_count >= cfg.grace)
     wins = jnp.concatenate([st.mon_buf[:, :, CPU], st.mon_buf[:, :, MEM]])
     age = jnp.arange(W)[None, :]
     vrow = age >= (W - jnp.minimum(st.mon_count, W))[:, None]
     valid = jnp.concatenate([vrow, vrow])
-    fc_done = jnp.int32(0)
+    fc_done = fc_tiles = jnp.int32(0)
     if cfg.forecaster == "persist":
         with jax.named_scope("forecast"):
             mean, var = persistence_peak(wins, valid)
     elif bucket is not None:
-        mean, var, n_pass = _bucketed_forecast(
+        mean, var, n_pass, fc_tiles = _bucketed_forecast(
             cfg, model, wins, valid, ready, bucket)
         fc_done = n_pass * jnp.int32(2 * bucket)
     else:
-        def _model(args):
-            w, v = args
-            fc = model.forecast_batch(w, cfg.horizon, valid=v)
-            peak, pvar = peak_over_horizon(fc)
-            return peak.astype(jnp.float32), pvar.astype(jnp.float32)
-
-        def _skip(args):
-            z = jnp.zeros((2 * AC,), jnp.float32)
-            return z, z
-
         with jax.named_scope("forecast"):
-            mean, var = jax.lax.cond(ready.any(), _model, _skip,
-                                     (wins, valid))
+            fc, fc_tiles = model.forecast_packed(
+                wins, cfg.horizon, valid=valid,
+                live=jnp.concatenate([ready, ready]))
+            mean, var = peak_over_horizon(fc)
+            mean, var = mean.astype(jnp.float32), var.astype(jnp.float32)
         fc_done = jnp.where(ready.any(), jnp.int32(2 * AC), jnp.int32(0))
 
     req_rows = jnp.concatenate([req[:, :, CPU].reshape(AC),
@@ -419,7 +422,7 @@ def _shaped_demands(cfg, model, tr: DeviceTrace, st: SimState,
     ready_tbl = ready.reshape(A, C)
     fc_rows = 2 * ready.sum().astype(jnp.int32)
     return (jnp.where(ready_tbl[:, :, None], shaped_tbl, demand), st,
-            fc_rows, fc_done)
+            fc_rows, fc_done, fc_tiles)
 
 
 def _shape_problem(cfg, tr: DeviceTrace, st: SimState, demand: Array,
@@ -849,10 +852,10 @@ def fused_tick(cfg, model, tr: DeviceTrace, st: SimState,
         # masks, all-zero allocations over an all-zero table), so no
         # gate is needed.  The model call inside `_shaped_demands` runs
         # under a nested `forecast` scope (`tick/shape/forecast`).
-        fc_rows = fc_done = jnp.int32(0)
+        fc_rows = fc_done = fc_tiles = jnp.int32(0)
         if cfg.policy != "baseline":
             with jax.named_scope("shape"):
-                demand, st, fc_rows, fc_done = _shaped_demands(
+                demand, st, fc_rows, fc_done, fc_tiles = _shaped_demands(
                     cfg, model, tr, st, tick, bucket)
                 if rec:
                     obs_dem = demand.sum((0, 1))  # (2,) shaped-demand totals
@@ -943,6 +946,7 @@ def fused_tick(cfg, model, tr: DeviceTrace, st: SimState,
                 used_cpu=used[CPU], used_mem=used[MEM],
                 alloc_cpu=alloc[CPU], alloc_mem=alloc[MEM],
                 forecast_rows=fc_rows, forecast_rows_done=fc_done,
+                forecast_tile_rows=fc_tiles,
                 lead=jnp.int32(0) if lead is None else lead)
 
             if rec:

@@ -61,6 +61,7 @@ def test_smoke_phases_rehearse_on_cpu(smoke, capsys):
                  "PASS scan == host engine", "PASS gp forecast mean",
                  "PASS arima forecast var", "PASS pallas gram (rbf)",
                  "PASS full run seed 1", f"FAIL {KERNEL_CHECK} -- 0 ",
+                 "PASS cohort chunk from tick 64, bucket ",
                  "# gp on chip: 8 rows alone vs in the 32-row batch: "
                  "bit-identical"):
         assert line in out, (line, out)

@@ -1,6 +1,7 @@
 """Forecasting module tests (paper §3.1): accuracy, uncertainty,
 degenerate inputs, batching."""
 import dataclasses
+import functools
 
 import jax.numpy as jnp
 import numpy as np
@@ -165,3 +166,97 @@ def test_forecast_batch_runs_one_tile_program(model):
     np.testing.assert_array_equal(cohort.var.reshape(40, 3), full.var)
     np.testing.assert_array_equal(plain.mean, full.mean[:TILE_ROWS])
     np.testing.assert_array_equal(plain.var, full.var[:TILE_ROWS])
+
+
+_LIVE_MODELS = [GPForecaster(GPConfig(opt_steps=2)), ARIMAForecaster()]
+
+
+def _live_rows(mask: str) -> np.ndarray:
+    """(3, 20) live masks: three members with 1, 7 and 17 live rows at
+    scattered places, none live, or every row live."""
+    if mask == "ragged":
+        rng = np.random.default_rng(2)
+        live = np.zeros((3, 20), bool)
+        for m, k in enumerate((1, 7, 17)):
+            live[m, rng.choice(20, k, replace=False)] = True
+        return live
+    return np.full((3, 20), mask == "all_live")
+
+
+@functools.cache
+def _live_programs(model):
+    """Jitted (every tile under a vmap, packed, packed under a vmap),
+    built once a model for every mask."""
+    import jax
+
+    def packed(w, v, live):
+        return model.forecast_packed(w, 3, valid=v, live=live)
+    return (jax.jit(jax.vmap(lambda w, v: model.forecast_batch(
+        w, 3, valid=v))), jax.jit(packed), jax.jit(jax.vmap(packed)))
+
+
+@pytest.mark.parametrize("mask", ["ragged", "all_dead", "all_live"])
+@pytest.mark.parametrize("model", _LIVE_MODELS, ids=["gp", "arima"])
+def test_live_rows_run_in_the_fewest_tiles(model, mask):
+    """With a ``live`` mask the tile loop runs ceil(live rows /
+    TILE_ROWS) tiles, the live rows of an outer vmap's members packed
+    together; a live row reads the bits ``forecast_batch`` gives
+    it, a dead row reads 0, and a batch with no live row runs no tile."""
+    import jax
+
+    from repro.core.forecast.base import TILE_ROWS
+    rng = np.random.default_rng(1)
+    w = rng.uniform(0.5, 4.0, (3, 20, 24)).astype(np.float32)
+    v = np.ones((3, 20, 24), bool)
+    v[:, ::3, :6] = False
+    live = _live_rows(mask)
+
+    def tiles(n_live):
+        return -(-int(n_live) // TILE_ROWS) * TILE_ROWS
+
+    full, packed, cohort = _live_programs(model)
+    full = full(w, v)
+    runs = [("cohort", *cohort(w, v, live), slice(None), tiles(live.sum()))]
+    runs += [(f"member {m}", *packed(w[m], v[m], live[m]), m,
+              tiles(live[m].sum())) for m in range(3)]
+    for name, fc, rows, m, want in runs:
+        assert np.all(np.asarray(rows) == want), (name, rows, want)
+        for got, ref in ((fc.mean, full.mean), (fc.var, full.var)):
+            got, ref, lv = np.asarray(got), np.asarray(ref)[m], live[m]
+            np.testing.assert_array_equal(got[lv], ref[lv], err_msg=name)
+            assert not got[~lv].any(), name
+
+
+@pytest.mark.parametrize("model", _LIVE_MODELS, ids=["gp", "arima"])
+def test_live_rows_run_the_same_tile_program(model):
+    """The packed path's ``while`` body holds, op for op, the
+    ``f32[16,24]`` tile program ``forecast_batch``'s ``scan`` runs:
+    packing changes which tiles run, never what a tile computes."""
+    import jax
+
+    from repro.core.forecast.base import TILE_ROWS
+    w = np.ones((40, 24), np.float32)
+    v = np.ones((40, 24), bool)
+    live = np.arange(40) % 3 == 0
+    tile = f"f32[{TILE_ROWS},24]"
+
+    def loops(jaxpr, loop):
+        for e in jaxpr.eqns:
+            if e.primitive.name == loop and tile in str(e.params):
+                yield e
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from loops(sub, loop)
+
+    def body(jaxpr, loop):
+        eqn = next(loops(jaxpr.jaxpr, loop))
+        sub = eqn.params["jaxpr" if loop == "scan" else "body_jaxpr"].jaxpr
+        return [(e.primitive.name, tuple(str(x.aval) for x in e.invars),
+                 tuple(str(x.aval) for x in e.outvars)) for e in sub.eqns]
+
+    plain = body(jax.make_jaxpr(lambda w, v: model.forecast_batch(
+        w, 3, valid=v))(w, v), "scan")
+    packed = body(jax.make_jaxpr(lambda w, v, l: model.forecast_packed(
+        w, 3, valid=v, live=l))(w, v, live), "while")
+    assert len(plain) > 50
+    assert any(packed[i:i + len(plain)] == plain
+               for i in range(len(packed) - len(plain) + 1))

@@ -16,6 +16,7 @@ The device-resident engine's contracts, in order of strength:
 """
 import dataclasses
 import math
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -365,6 +366,107 @@ def test_leap_with_bucketed_gp_matches_uniform_unbucketed():
         dataclasses.replace(cfg, leap=True), wl, chunk=16)
     assert _results_equal(plain, fast)
     assert plain.calibration == fast.calibration
+
+
+def _every_tile(model):
+    """chip_smoke.py's ``EveryTile``: the model with its packed batches
+    run as plain ones, every tile run."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", Path(__file__).resolve().parents[1] / "chip_smoke.py")
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    return smoke.EveryTile(model)
+
+
+def test_packed_tiles_match_every_tile_in_a_cohort_chunk():
+    """A GP cohort chunk of the bucketed path, whose members need
+    different numbers of passes, gives the same state and per-tick
+    totals, bit for bit, with the tile loop packing the cohort's ready
+    rows as with every tile run; the rows offered
+    (``forecast_rows_done``) do not move, and the tiles run hold every
+    ready row and no more than the members' rows offered."""
+    import jax
+
+    from repro.sim import step
+    from repro.sim.state import init_state
+    cfg = dataclasses.replace(
+        BASE, policy="pessimistic", forecaster="gp",
+        workload=dataclasses.replace(WL, n_apps=12))
+    wls = [generate(dataclasses.replace(cfg.workload, seed=s))
+           for s in (0, 1, 2)]
+    tr = step._device_trace(wls, batched=True)
+    st = init_state(cfg, wls[0].n_apps, wls[0].max_components, batch=3)
+    model = step._make_model(cfg)
+
+    def program(m):
+        return jax.jit(jax.vmap(step._chunk_body(cfg, m, 16, 8)[0]))
+
+    packed, every = program(model), program(_every_tile(model))
+    st, _ = packed(tr, st)              # past the grace period
+    st_p, ms_p = packed(tr, st)
+    st_e, ms_e = every(tr, st)
+    for a, b in zip(jax.tree.leaves(st_p), jax.tree.leaves(st_e)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for f in dataclasses.fields(ms_p):
+        if f.name != "forecast_tile_rows":
+            np.testing.assert_array_equal(getattr(ms_p, f.name),
+                                          getattr(ms_e, f.name), f.name)
+    ready = np.asarray(ms_p.forecast_rows)          # (members, ticks)
+    done = np.asarray(ms_p.forecast_rows_done)
+    tiles = np.asarray(ms_p.forecast_tile_rows).max(axis=0)
+    assert (done.min(0) < done.max(0)).any()        # members part ways
+    assert (tiles % 16 == 0).all() and (tiles >= ready.sum(0)).all()
+    assert (tiles <= 3 * done.max(0)).all()
+    assert tiles.sum() < 3 * done.max(0).sum()
+
+
+def test_unbucketed_cohort_chunk_matches_solo_chunks():
+    """The un-bucketed GP chunk program (``bucket=None``: the whole
+    monitor table offered, its ready rows live) builds and runs under
+    the cohort vmap, and each member's state and per-tick totals equal
+    its solo chunks bit for bit; a member is offered all ``2 * AC`` rows
+    on a tick with a ready row and none on other ticks, and the tiles run
+    hold the cohort's ready rows, rounded up to whole tiles."""
+    import jax
+
+    from repro.sim import step
+    from repro.sim.state import init_state
+    cfg = dataclasses.replace(
+        BASE, policy="pessimistic", forecaster="gp",
+        workload=dataclasses.replace(WL, n_apps=12))
+    wls = [generate(dataclasses.replace(cfg.workload, seed=s))
+           for s in (0, 1, 2)]
+    shapes = step._shapes_key(wls[0], cfg)
+    N, C = wls[0].n_apps, wls[0].max_components
+    AC = cfg.cluster.max_running_apps * C
+
+    def chunks(cohort, tr, st):
+        fn = step._chunk_fn(cfg, 16, shapes, cohort, None)
+        parts = []
+        for _ in range(2):                  # the second past the grace
+            st, ms = fn(tr, st)
+            parts.append(ms)
+        return jax.device_get(st), step._concat_metrics(parts, int(cohort))
+
+    st_c, ms_c = chunks(True, step._device_trace(wls, batched=True),
+                        init_state(cfg, N, C, batch=len(wls)))
+    for m, wl in enumerate(wls):
+        st_s, ms_s = chunks(False, step._device_trace([wl], batched=False),
+                            init_state(cfg, N, C))
+        for a, b in zip(jax.tree.leaves(st_c), jax.tree.leaves(st_s)):
+            np.testing.assert_array_equal(np.asarray(a)[m], np.asarray(b))
+        for f in dataclasses.fields(ms_c):
+            if f.name != "forecast_tile_rows":
+                np.testing.assert_array_equal(
+                    getattr(ms_c, f.name)[m], getattr(ms_s, f.name), f.name)
+    ready = np.asarray(ms_c.forecast_rows)          # (members, ticks)
+    done = np.asarray(ms_c.forecast_rows_done)
+    tiles = np.asarray(ms_c.forecast_tile_rows)
+    assert ready.any()
+    np.testing.assert_array_equal(done, np.where(ready > 0, 2 * AC, 0))
+    np.testing.assert_array_equal(tiles.min(0), tiles.max(0))
+    np.testing.assert_array_equal(tiles[0], -(-ready.sum(0) // 16) * 16)
 
 
 # ----------------------------------------------------------------------
